@@ -3,17 +3,16 @@
 1. **x64 is scoped, never global.**  ``jax.config.update("jax_enable_
    x64", ...)`` flips dtype semantics for EVERY jitted program in the
    process — the crypto kernels are traced under 32-bit semantics and
-   silently produce wrong limbs afterwards.  The proven spelling is
-   the scoped context manager ``with jax.experimental.enable_x64():``
-   (see ``fork_choice/device_proto_array.py`` throughout).
+   silently produce wrong limbs afterwards.  The spelling is the scoped
+   context manager ``with jax.enable_x64(True):`` (see
+   ``fork_choice/device_proto_array.py`` throughout);
+   ``jax.experimental.enable_x64`` no longer exists in the installed jax.
 
-2. **One shard_map spelling.**  This container's jax (0.4.37) only has
-   ``jax.experimental.shard_map.shard_map`` with ``check_rep`` — the
-   top-level ``jax.shard_map`` and the ``check_vma`` kwarg exist only
-   in newer jax.  The proven portable spelling is the experimental
-   import + an explicit ``check_rep=False`` (``parallel/bls_shard.py``
-   ``sharded_g1_sum``, validated on single-chip AND the multichip
-   dryrun).
+2. **One shard_map spelling.**  ``jax.shard_map(..., check_vma=False)``
+   — the top-level function of the installed jax.  The
+   ``jax.experimental.shard_map`` module and its ``check_rep`` kwarg are
+   deprecated; ``check_vma=False`` is explicit because the kernels close
+   over replicated constant tables (``parallel/mesh.mesh_program``).
 
 3. **No ``jnp.`` computation at import time.**  A module-level
    ``jnp.arange(...)`` materializes a device buffer (and may initialize
@@ -37,8 +36,8 @@ def _root(chain: str) -> str:
 @register
 class JaxHygieneChecker(Checker):
     name = "jax-hygiene"
-    doc = ("enable_x64 only as a scoped context manager; shard_map "
-           "only via jax.experimental.shard_map with check_rep=False; "
+    doc = ("enable_x64 only as the scoped jax.enable_x64(True); "
+           "shard_map only as jax.shard_map with check_vma=False; "
            "no jnp. computation at module import time")
 
     def check(self, ctx: Context, path: str, tree: ast.AST,
@@ -46,17 +45,27 @@ class JaxHygieneChecker(Checker):
         out: List[Finding] = []
         self._scan(tree, path, out, depth=0, func="module")
         for node in ast.walk(tree):
-            if isinstance(node, ast.ImportFrom) and \
-                    node.module in ("jax", "jax.sharding") and \
-                    any(a.name == "shard_map" for a in node.names):
+            if not isinstance(node, ast.ImportFrom) or not node.module:
+                continue
+            names = {a.name for a in node.names}
+            if node.module == "jax.experimental.shard_map" or (
+                    node.module.startswith("jax.")
+                    and "shard_map" in names):
                 out.append(Finding(
                     self.name, path, node.lineno,
-                    f"shard_map imported from {node.module!r} — only "
-                    f"jax.experimental.shard_map exists across the "
-                    f"jax versions this repo runs on",
-                    hint="from jax.experimental.shard_map import "
-                         "shard_map",
+                    f"shard_map imported from {node.module!r} — the "
+                    f"installed spelling is the top-level jax.shard_map",
+                    hint="call jax.shard_map(..., check_vma=False)",
                     detail="shard-map-import"))
+            if node.module == "jax.experimental" and \
+                    "enable_x64" in names:
+                out.append(Finding(
+                    self.name, path, node.lineno,
+                    "enable_x64 imported from jax.experimental — gone "
+                    "from the installed jax",
+                    hint="use the scoped form: "
+                         "'with jax.enable_x64(True):'",
+                    detail="enable-x64-import"))
         return out
 
     def _scan(self, node: ast.AST, path: str, out: List[Finding],
@@ -93,37 +102,39 @@ class JaxHygieneChecker(Checker):
                     "process (the crypto kernels trace under 32-bit "
                     "semantics)",
                     hint="use the scoped form: "
-                         "'with jax.experimental.enable_x64():'",
+                         "'with jax.enable_x64(True):'",
                     detail=f"enable-x64-config:{func}"))
 
-        if chain == "jax.shard_map" or \
-                (chain.endswith(".shard_map")
-                 and _root(chain) == "jax"
-                 and "experimental" not in chain):
+        if chain == "jax.experimental.enable_x64":
             out.append(Finding(
                 self.name, path, node.lineno,
-                f"{chain}(...) — the top-level shard_map only exists "
-                f"in newer jax",
-                hint="from jax.experimental.shard_map import "
-                     "shard_map",
-                detail=f"shard-map-spelling:{func}"))
-        elif chain == "shard_map" or chain.endswith(".shard_map"):
-            # elif: a wrong-spelling call is ONE defect — reporting
-            # the missing check_rep too would mint a second waiver key
-            # that goes stale the moment the import is fixed.
+                "jax.experimental.enable_x64 — gone from the installed jax",
+                hint="use the scoped form: 'with jax.enable_x64(True):'",
+                detail=f"enable-x64-spelling:{func}"))
+
+        if chain == "jax.shard_map":
             kw = {k.arg: k.value for k in node.keywords}
-            ok = isinstance(kw.get("check_rep"), ast.Constant) and \
-                kw["check_rep"].value is False
+            ok = isinstance(kw.get("check_vma"), ast.Constant) and \
+                kw["check_vma"].value is False
             if not ok:
                 out.append(Finding(
                     self.name, path, node.lineno,
-                    "shard_map call without check_rep=False — the one "
-                    "spelling proven on this container's jax 0.4.37 "
-                    "AND the multichip dryrun (check_vma / implicit "
-                    "rep-checking are version-specific)",
-                    hint="pass check_rep=False explicitly (mirror "
-                         "parallel/bls_shard.sharded_g1_sum)",
-                    detail=f"shard-map-check-rep:{func}"))
+                    "jax.shard_map call without check_vma=False — the "
+                    "kernels close over replicated constant tables, "
+                    "which the varying-axes check rejects",
+                    hint="pass check_vma=False explicitly (mirror "
+                         "parallel/mesh.mesh_program)",
+                    detail=f"shard-map-check-vma:{func}"))
+        elif chain == "shard_map" or chain.endswith(".shard_map"):
+            # elif: a wrong-spelling call is ONE defect — reporting a
+            # missing check_vma too would mint a second waiver key that
+            # goes stale the moment the spelling is fixed.
+            out.append(Finding(
+                self.name, path, node.lineno,
+                f"{chain}(...) — the installed spelling is the top-level "
+                f"jax.shard_map",
+                hint="call jax.shard_map(..., check_vma=False)",
+                detail=f"shard-map-spelling:{func}"))
 
         if depth == 0 and (_root(chain) == "jnp"
                            or chain.startswith("jax.numpy.")):

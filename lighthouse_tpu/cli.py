@@ -27,20 +27,20 @@ def _add_common(p: argparse.ArgumentParser) -> None:
     p.add_argument("--dump-config", default="",
                    help="write the effective chain spec YAML to PATH and "
                         "exit (`clap_utils` --dump-config)")
-    p.add_argument("--compile-cache", default="", metavar="DIR",
-                   help="enable JAX's persistent compilation cache at DIR "
-                        "(default: <repo>/.jax_cache; 'off' disables) so a "
-                        "restarted node never re-pays the cold XLA compile "
-                        "of the device pipelines")
+    p.add_argument("--no-compile-cache", action="store_true",
+                   help="run without JAX's persistent compilation cache "
+                        "(by default it lives at JAX_COMPILATION_CACHE_DIR, "
+                        "else <repo>/.jax_cache, so a restarted node never "
+                        "re-pays the cold XLA compile of the device "
+                        "pipelines)")
 
 
 def _maybe_enable_compile_cache(args) -> None:
-    flag = getattr(args, "compile_cache", "")
-    if flag == "off":
+    if getattr(args, "no_compile_cache", False):
         return
     from .common.compile_cache import enable
 
-    enable(flag or None)
+    enable()
 
 
 def _effective_spec(args):
@@ -379,18 +379,12 @@ def cmd_account(args) -> int:
 
 def cmd_warmup(args) -> int:
     """Pre-compile the device hot paths into the persistent cache
-    (`--compile-cache`), so the next node process pays disk reads, not
-    the ~17-minute cold XLA compile, on its first slot.  Off-TPU this is
-    a no-op (the warmup API reports it)."""
+    (``JAX_COMPILATION_CACHE_DIR``, else ``<repo>/.jax_cache``), so the
+    next node process pays disk reads, not the cold XLA compile, on its
+    first slot.  Off-TPU this is a no-op (the warmup API reports it)."""
     from .common.compile_cache import DEFAULT_BUCKETS, enable, warmup
 
-    if args.compile_cache == "off":
-        # A warmup that persists nothing is minutes of compile thrown
-        # away the moment the process exits — refuse instead.
-        print(json.dumps({"error": "warmup requires a persistent cache; "
-                                   "drop --compile-cache off"}))
-        return 2
-    cache = enable(args.compile_cache or None)
+    cache = enable()
     buckets = []
     for part in (args.shapes.split(",") if args.shapes else []):
         sets, _, keys = part.partition("x")
@@ -477,8 +471,6 @@ def main(argv=None) -> int:
     wu = sub.add_parser("warmup",
                         help="pre-compile the device hot paths into the "
                              "persistent compilation cache")
-    wu.add_argument("--compile-cache", default="", metavar="DIR",
-                    help="cache directory (default: <repo>/.jax_cache)")
     wu.add_argument("--shapes", default="",
                     help="comma-separated (sets)x(keys) buckets, e.g. "
                          "'256x16,256x1' (default: the slot-path buckets)")

@@ -77,8 +77,8 @@ def _declare(name: str, type: str, default: object, doc: str,
 
 # ---------------------------------------------------------------------------
 # The registry.  Every LIGHTHOUSE_TPU_* knob in the tree, plus the
-# LH_TPU_JAX_CACHE compile-cache path.  Keep docs to one line — they
-# render as the README knob table.
+# compile-cache directory.  Keep docs to one line — they render as the
+# README knob table.
 # ---------------------------------------------------------------------------
 
 # -- crypto / BLS hot path --
@@ -233,17 +233,12 @@ _declare("LIGHTHOUSE_TPU_SLO_PROOF_SERVE_MS", "float", 50.0,
 _declare("LIGHTHOUSE_TPU_SLO_HYSTERESIS", "int", 2,
          "Consecutive evaluations a new health state must hold before "
          "the node transitions.", min_value=1)
-
 # -- toolchain --
-# The registry default is the REAL repo-relative path (usable by any
-# accessor call); the README renders it as "<repo>/.jax_cache".
-_REPO_ROOT = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
-_declare("LH_TPU_JAX_CACHE", "str",
-         os.path.join(_REPO_ROOT, ".jax_cache"),
-         "Directory of the persistent XLA compilation cache "
-         "(default: <repo>/.jax_cache).",
-         display_default="<repo>/.jax_cache")
+# JAX reads this variable itself; the registry entry lets the one
+# compile-cache rule (common/compile_cache.default_dir) read it too.
+_declare("JAX_COMPILATION_CACHE_DIR", "str", "",
+         "Persistent XLA compilation cache directory (read by JAX "
+         "itself); unset: <repo>/.jax_cache.", display_default="unset")
 
 
 # ---------------------------------------------------------------------------

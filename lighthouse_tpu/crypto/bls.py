@@ -332,6 +332,8 @@ def register_backend(name: str, backend) -> None:
 
 def set_backend(name: str) -> None:
     global _active
+    if name == "tpu" and name not in _BACKENDS:
+        from . import tpu_backend  # noqa: F401  (registers itself)
     _active = _BACKENDS[name]
 
 

@@ -37,6 +37,7 @@ from .pairing_kernel import (
     fq2_add, fq2_sub, fq2_neg, fq2_conj, fq2_mul, fq2_mul_many, fq2_inv,
     point_add, point_select, point_identity, _G2ops,
     pack_planes, unpack_planes, CONSTS_PLANES, _COMPILER_PARAMS,
+    _mm_scratch,
 )
 
 # LSB-first bits of (p²+7)/16 — the sqrt-ladder exponent (758 bits).
@@ -147,20 +148,35 @@ def k_sswu_map(t):
     B = _kc2("H2C_B", m)
     gx1 = fq2_add(fq2_mul(fq2_mul(x1, x1), x1), fq2_add(fq2_mul(A, x1), B))
     c = k_fq2_pow_e16(gx1)
-    # 8-candidate sqrt (see ..hash_to_curve.sqrt_or_z_times).
-    y1 = _fq2_zero(m)
-    s = _fq2_zero(m)
-    is_qr = jnp.zeros((1, m), bool)
+    # 8-candidate sqrt (see ..hash_to_curve.sqrt_or_z_times), one
+    # candidate per loop step: c·E8⁻ᵏ against gx1 for k < 4 (→ y1), then
+    # c·Tₖ against Z·gx1 (→ s); the last square root found wins, as in
+    # the straight-line order.
     zgx1 = fq2_mul(Zc, gx1)
-    for k in range(4):
-        cand = fq2_mul(c, _kc2(f"H2C_E8I{k}", m))
-        ok = k_fq2_eq(fq2_mul(cand, cand), gx1)
-        y1 = _fq2_sel(ok, cand, y1)
-        is_qr = is_qr | ok
-    for k in range(4):
-        cand = fq2_mul(c, _kc2(f"H2C_T{k}", m))
-        ok = k_fq2_eq(fq2_mul(cand, cand), zgx1)
-        s = _fq2_sel(ok, cand, s)
+    consts = [_KC[f"H2C_E8I{k}{u}"] for k in range(4) for u in (0, 1)] \
+        + [_KC[f"H2C_T{k}{u}"] for k in range(4) for u in (0, 1)]
+
+    def candidate(j, carry):
+        y1, s, is_qr = carry
+        const = []
+        for u in (0, 1):
+            pick = consts[u]
+            for k in range(1, 8):
+                pick = jnp.where(j == k, consts[2 * k + u], pick)
+            const.append(_mat(pick, m))
+        cand = fq2_mul(c, (const[0], const[1]))
+        first = j < 4
+        target = _fq2_sel(jnp.full((1, m), first), gx1, zgx1)
+        ok = k_fq2_eq(fq2_mul(cand, cand), target)
+        y1 = _fq2_sel(ok & first, cand, y1)
+        s = _fq2_sel(ok & ~first, cand, s)
+        return y1, s, is_qr | (ok & first).astype(jnp.int32)
+
+    # is_qr rides the loop as int32: Mosaic cannot carry an i1 vector.
+    y1, s, is_qr = jax.lax.fori_loop(
+        0, 8, candidate,
+        (_fq2_zero(m), _fq2_zero(m), jnp.zeros((1, m), jnp.int32)))
+    is_qr = is_qr != 0
     x2 = fq2_mul(tv1, x1)
     y2 = fq2_mul(fq2_mul(tv1, t), s)
     x = _fq2_sel(is_qr, x1, x2)
@@ -235,9 +251,13 @@ def k_psi(p):
 
 def k_clear_cofactor(p):
     """Budroni–Pintore: h_eff·P = ([x²]P − [x]P − P) + ψ([x]P − P) +
-    ψ²([2]P) — twin of :func:`..hash_to_curve.clear_cofactor`."""
-    t1 = k_g2_neg(k_g2_mul_x_abs(p))            # [x]P (x < 0)
-    t2 = k_g2_neg(k_g2_mul_x_abs(t1))           # [x²]P
+    ψ²([2]P) — twin of :func:`..hash_to_curve.clear_cofactor`.  The two
+    [x]-multiplies run as one two-step loop (one compiled ladder)."""
+    def times_x(_i, carry):
+        q, _prev = carry
+        return k_g2_neg(k_g2_mul_x_abs(q)), q   # [x]q (x < 0)
+
+    t2, t1 = jax.lax.fori_loop(0, 2, times_x, (p, p))  # [x²]P, [x]P
     acc = point_add(_G2ops, t2, k_g2_neg(t1))
     acc = point_add(_G2ops, acc, k_g2_neg(p))
     acc = point_add(_G2ops, acc, k_psi(point_add(_G2ops, t1, k_g2_neg(p))))
@@ -245,8 +265,8 @@ def k_clear_cofactor(p):
 
 
 def _hash_g2_kernel(cref, xbits_ref, pbits_ref, band_ref, e16_ref, u_ref,
-                    out_ref):
-    _bind_consts(cref, xbits_ref, pbits_ref, band_ref)
+                    out_ref, *mm):
+    _bind_consts(cref, xbits_ref, pbits_ref, band_ref, mm)
     _KC["e16"] = e16_ref
     # in_mosaic is a trace-time flag: scope it to this trace so an eager /
     # interpret drive of the k_* helpers afterwards doesn't inherit it
@@ -292,6 +312,7 @@ def hash_g2_kernel_call(u_planes):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((4 * BLOCK_ROWS, g * LANE_BLOCK),
                                        jnp.uint32),
+        scratch_shapes=_mm_scratch(2 * LANE_BLOCK),
         compiler_params=_COMPILER_PARAMS,
     )(*_htc_const_args(), u_planes)
 

@@ -104,42 +104,73 @@ ONE_MONT = to_mont(1)
 # Device ops (pure jnp; batched over leading dims; limb axis = -1)
 # ---------------------------------------------------------------------------
 
+# Carry resolution without a per-limb ripple: a 26-step unrolled carry
+# chain per add made the pairing programs millions of HLO ops (the
+# mesh-sharded verify lowered to ~1M lines and compiled for minutes).
+# Once every limb carries at most 1, limb k generates (g), propagates
+# (p: limb == 0xFFFF) or kills the carry, and one integer add of the
+# packed words G + (G|P) runs the whole chain: bit k of
+# (G + (G|P) + cin) ^ G ^ (G|P) is the carry INTO limb k.  The results
+# are the same limbs as the ripple's.
+
+_POW2 = np.uint32(1) << np.arange(LIMBS, dtype=np.uint32)
+
+
+def _pack_bits(bits: jnp.ndarray) -> jnp.ndarray:
+    """(..., w ≤ 26) 0/1 limbs → (...,) word, limb k at bit k."""
+    w = bits.shape[-1]
+    return jnp.sum((bits * _POW2[:w]).astype(jnp.int32), axis=-1
+                   ).astype(jnp.uint32)
+
+
+def _shift_up(x: jnp.ndarray) -> jnp.ndarray:
+    """Limb k ← limb k−1, limb 0 ← 0."""
+    return jnp.concatenate([jnp.zeros_like(x[..., :1]), x[..., :-1]],
+                           axis=-1)
+
+
+def _resolve01(s: jnp.ndarray, cin=None):
+    """(..., w ≤ 26) columns < 2^17 → 16-bit limbs of Σ s_k 2^16k + cin
+    (mod 2^16w) and the (...,) carry out."""
+    w = s.shape[-1]
+    g = _pack_bits(s >> np.uint32(LIMB_BITS))
+    b = g | _pack_bits((s == MASK).astype(jnp.uint32))
+    x = g + b if cin is None else g + b + cin
+    x = x ^ g ^ b
+    c = ((x[..., None] & _POW2[:w]) != 0).astype(jnp.uint32)
+    return (s + c) & MASK, (x >> np.uint32(w)) & np.uint32(1)
+
+
+def _carry_cols(t: jnp.ndarray, ncols: int, keep_carry: bool) -> jnp.ndarray:
+    """Normalize ``ncols`` uint32 columns (< 2^32) to 16-bit limbs; the final
+    carry is appended iff ``keep_carry`` (else reduced mod 2^(16·ncols))."""
+    top = t[..., ncols - 1] >> np.uint32(LIMB_BITS)
+    s = (t & MASK) + _shift_up(t >> np.uint32(LIMB_BITS))   # < 2^17
+    out, cin = [], None
+    for g0 in range(0, ncols, LIMBS):
+        limbs, cin = _resolve01(s[..., g0:g0 + LIMBS], cin)
+        out.append(limbs)
+    if keep_carry:
+        out.append((top + cin)[..., None])
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=-1)
+
+
 def _carry_u32(x: jnp.ndarray) -> jnp.ndarray:
-    """Normalize uint32 limb values (< 2^32) to 16-bit limbs, unrolled
-    carry chain.  The value must fit 26 limbs (guaranteed by the < 4N
-    bound; R = 2^416 leaves 33+ spare bits)."""
-    out = []
-    carry = jnp.zeros_like(x[..., 0])
-    for i in range(LIMBS):
-        v = x[..., i] + carry
-        out.append(v & MASK)
-        carry = v >> np.uint32(LIMB_BITS)
-    return jnp.stack(out, axis=-1)
-
-
-def _carry_i32(x: jnp.ndarray) -> jnp.ndarray:
-    """Signed carry normalization (int32 limb values, possibly negative;
-    total value must be in [0, 2^416))."""
-    out = []
-    carry = jnp.zeros_like(x[..., 0])
-    for i in range(LIMBS):
-        v = x[..., i] + carry
-        out.append(v & jnp.int32(0xFFFF))
-        carry = v >> 16  # arithmetic shift: floor division by 2^16
-    return jnp.stack(out, axis=-1).astype(jnp.uint32)
+    """Normalize uint32 limb values (< 2^32) to 16-bit limbs.  The value
+    must fit 26 limbs (guaranteed by the < 4N bound; R = 2^416 leaves
+    33+ spare bits)."""
+    return _carry_cols(x, LIMBS, keep_carry=False)
 
 
 def _cond_sub(x: jnp.ndarray, k_limbs: np.ndarray) -> jnp.ndarray:
     """x - K if x ≥ K else x, branch-free (normalized limb input)."""
-    d = x.astype(jnp.int32) - jnp.asarray(k_limbs, jnp.int32)
-    out = []
-    carry = jnp.zeros_like(d[..., 0])
-    for i in range(LIMBS):
-        v = d[..., i] + carry
-        out.append(v & jnp.int32(0xFFFF))
-        carry = v >> 16
-    d_norm = jnp.stack(out, axis=-1).astype(jnp.uint32)
-    no_borrow = carry == 0
+    k = jnp.asarray(k_limbs, jnp.uint32)
+    g = _pack_bits((x < k).astype(jnp.uint32))        # borrow generated
+    b = g | _pack_bits((x == k).astype(jnp.uint32))   # … or propagated
+    w = (g + b) ^ g ^ b
+    borrow = ((w[..., None] & _POW2) != 0).astype(jnp.uint32)
+    d_norm = (x - k - borrow) & MASK
+    no_borrow = (w >> np.uint32(LIMBS)) == 0
     return jnp.where(no_borrow[..., None], d_norm, x)
 
 
@@ -156,16 +187,20 @@ def add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
 
 def sub(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """a - b mod N (inputs < 2N → a - b + 2N ∈ (0, 4N) → reduced < 2N)."""
-    d = a.astype(jnp.int32) + jnp.asarray(N2_LIMBS, jnp.int32) - b.astype(jnp.int32)
-    return _cond_sub(_carry_i32(d), N2_LIMBS)
+    """a - b mod N (inputs < 2N → a - b + 2N ∈ (0, 4N) → reduced < 2N),
+    computed as a + 2N + ~b + 1 (the 2^416 wrap dropped)."""
+    t = a + jnp.asarray(N2_LIMBS) + (MASK - b)          # < 3·2^16
+    s = (t & MASK) + _shift_up(t >> np.uint32(LIMB_BITS))
+    d, _ = _resolve01(s, jnp.ones_like(s[..., 0]))
+    return _cond_sub(d, N2_LIMBS)
 
 
 def neg(a: jnp.ndarray) -> jnp.ndarray:
     """2N - a ≡ -a (mod N); input < 2N → output ≤ 2N (2N ≡ 0 is a valid
-    lazy zero and the next add/mul handles it)."""
-    d = jnp.asarray(N2_LIMBS, jnp.int32) - a.astype(jnp.int32)
-    return _carry_i32(d)
+    lazy zero and the next add/mul handles it).  2N + ~a + 1, wrap
+    dropped."""
+    s = jnp.asarray(N2_LIMBS) + (MASK - a)               # < 2^17
+    return _resolve01(s, jnp.ones_like(s[..., 0]))[0]
 
 
 def muls(a: jnp.ndarray, s: int) -> jnp.ndarray:
@@ -258,8 +293,11 @@ from functools import lru_cache as _lru_cache
 
 
 @_lru_cache(maxsize=4)
-def _band_sel_dev(ncols: int):
-    return jnp.asarray(band_sel_matrix(ncols))
+def _band_sel(ncols: int) -> np.ndarray:
+    # Cached as numpy, not as a jax array: a jnp.asarray made inside one
+    # jit trace is that trace's tracer, and a later trace reading the
+    # cache would leak it.
+    return band_sel_matrix(ncols)
 
 
 def _band_columns_mxu(a: jnp.ndarray, b: jnp.ndarray,
@@ -273,7 +311,7 @@ def _band_columns_mxu(a: jnp.ndarray, b: jnp.ndarray,
           .astype(jnp.float32).reshape(lead + (LIMBS * LIMBS,)))
     feat = jnp.concatenate([lo, hi], axis=-1)         # (..., 1352)
     t = jax.lax.dot_general(
-        feat, _band_sel_dev(ncols),
+        feat, _band_sel(ncols),
         dimension_numbers=(((feat.ndim - 1,), (0,)), ((), ())),
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
@@ -282,20 +320,6 @@ def _band_columns_mxu(a: jnp.ndarray, b: jnp.ndarray,
 
 def _band(a: jnp.ndarray, b: jnp.ndarray, ncols: int) -> jnp.ndarray:
     return (_band_columns_mxu if use_mxu() else _band_columns)(a, b, ncols)
-
-
-def _carry_cols(t: jnp.ndarray, ncols: int, keep_carry: bool) -> jnp.ndarray:
-    """Normalize ``ncols`` uint32 columns (< 2^23) to 16-bit limbs; the final
-    carry is appended iff ``keep_carry`` (else reduced mod 2^(16·ncols))."""
-    out = []
-    carry = jnp.zeros_like(t[..., 0])
-    for i in range(ncols):
-        v = t[..., i] + carry
-        out.append(v & MASK)
-        carry = v >> np.uint32(LIMB_BITS)
-    if keep_carry:
-        out.append(carry)
-    return jnp.stack(out, axis=-1)
 
 
 _NPRIME_LIMBS = int_to_limbs(NPRIME_INT)
@@ -317,9 +341,13 @@ def mont_mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     t_low = _carry_cols(t[..., :LIMBS], LIMBS, keep_carry=False)
     m = _carry_cols(_band(t_low, jnp.asarray(_NPRIME_LIMBS), LIMBS),
                     LIMBS, keep_carry=False)           # m = T·N' mod R
-    u = _band(m, jnp.asarray(N_LIMBS), 2 * LIMBS)
-    s = _carry_cols(t + u, 2 * LIMBS, keep_carry=True)  # (T + mN), exact
-    return s[..., LIMBS:2 * LIMBS]                      # / R  (low half ≡ 0)
+    s = t + _band(m, jnp.asarray(N_LIMBS), 2 * LIMBS)   # T + mN, < 2^24
+    s = (s & MASK) + _shift_up(s >> np.uint32(LIMB_BITS))
+    # T + mN ≡ 0 (mod R): the low 26 columns sum to 0 or exactly R, so
+    # the carry into limb 26 is 1 iff any of them is non-zero.  The
+    # final carry out of (T + mN)/R < 2N is zero.
+    cin = jnp.any(s[..., :LIMBS] != 0, axis=-1).astype(jnp.uint32)
+    return _resolve01(s[..., LIMBS:], cin)[0]
 
 
 def select(mask: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:

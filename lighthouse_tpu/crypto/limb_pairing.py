@@ -259,36 +259,97 @@ def miller_loop(g1_affine: jnp.ndarray, g2_affine: jnp.ndarray) -> jnp.ndarray:
 # Final exponentiation (cubed), x-power ladder
 # ---------------------------------------------------------------------------
 
-def _pow_x_abs(f: jnp.ndarray) -> jnp.ndarray:
-    """f^|x| by scanned square-and-multiply (64 static bits)."""
-    one = jnp.broadcast_to(jnp.asarray(T.FQ12_ONE_LIMBS), f.shape)
+# The final exponentiation as a step table — the SAME multiply sequence as
+# the straight-line HHT decomposition (f^-1 and conj(f)·f^-1, the easy
+# frob2 step, five |x|-ladders, the hard-part combines), run as one scan
+# over (op, dst, a, ta, b, tb) rows with ONE Fq12 multiply: dst ←
+# T(reg[a])·U(reg[b]) or a copy of T(reg[a]), T ∈ {id, conj},
+# U ∈ {id, conj, frob1, frob2}.  Straight-line, the ~30 multiplies and
+# five ladder loops made this one of the largest programs XLA compiles
+# here; the Pallas finalize kernel runs the same table.  Fq12
+# multiplication is symmetric in its operands limb for limb, which lets
+# the easy part's frob2(m)·m run as m·frob2(m).
 
-    def body(acc, bit):
-        acc = T.fq12_sqr(acc)
-        return jnp.where(bit.astype(bool), T.fq12_mul(acc, f), acc), None
-
-    acc, _ = jax.lax.scan(body, one, jnp.asarray(X_BITS_FULL))
-    return acc
+_R_F, _R_I, _R_A, _R_X, _R_K, _R_M, _R_ONE = range(7)
+_N_REGS = 7
+_OP_MUL, _OP_COPY = 0, 1
+_T_ID, _T_CONJ, _T_FROB1, _T_FROB2 = range(4)
 
 
-def _pow_u(f: jnp.ndarray) -> jnp.ndarray:
-    """f^u for the (negative) BLS parameter u — cyclotomic elements only
-    (inverse = conjugate)."""
-    return T.fq12_conj(_pow_x_abs(f))
+def _final_exp_program() -> np.ndarray:
+    """Flat (steps · 6,) int32 table of (op, dst, a, ta, b, tb) rows (a
+    1-D SMEM operand in the Pallas finalize kernel): the easy part from F
+    and I = F⁻¹, then the HHT hard part ×3 — step for step the
+    multiplies of the straight-line decomposition
+    (:func:`..pairing.final_exponentiation_cubed`,
+    :func:`..pairing_kernel.k_final_exp_cubed`), ending in register X."""
+    steps = []
+
+    def mul(dst, a, b, ta=_T_ID, tb=_T_ID):
+        steps.append((_OP_MUL, dst, a, ta, b, tb))
+
+    def pow_x_abs(base, tb=_T_ID):
+        """A ← base^|x| (square-and-multiply; the multiply only on set
+        bits — the straight-line ladders compute and discard the
+        others)."""
+        steps.append((_OP_COPY, _R_A, _R_ONE, _T_ID, _R_ONE, _T_ID))
+        for bit in X_BITS_FULL:
+            mul(_R_A, _R_A, _R_A)
+            if bit:
+                mul(_R_A, _R_A, base, tb=tb)
+
+    mul(_R_M, _R_F, _R_I, ta=_T_CONJ)                   # conj(f)·f⁻¹
+    mul(_R_M, _R_M, _R_M, tb=_T_FROB2)                  # m·frob2(m)
+    pow_x_abs(_R_M)
+    mul(_R_X, _R_A, _R_M, ta=_T_CONJ, tb=_T_CONJ)       # m1
+    pow_x_abs(_R_X)
+    mul(_R_X, _R_A, _R_X, ta=_T_CONJ, tb=_T_CONJ)       # k2
+    pow_x_abs(_R_X)
+    mul(_R_K, _R_A, _R_X, ta=_T_CONJ, tb=_T_FROB1)      # k3
+    pow_x_abs(_R_K)
+    steps.append((_OP_COPY, _R_X, _R_A, _T_ID, _R_A, _T_ID))
+    pow_x_abs(_R_X, tb=_T_CONJ)                         # pow_u(pow_u(k3))
+    mul(_R_X, _R_A, _R_K, ta=_T_CONJ, tb=_T_FROB2)
+    mul(_R_X, _R_X, _R_K, tb=_T_CONJ)                   # k4
+    mul(_R_A, _R_M, _R_M)
+    mul(_R_A, _R_A, _R_M)                               # m²·m
+    mul(_R_X, _R_X, _R_A)
+    return np.asarray(steps, np.int32).reshape(-1)
+
+
+FINAL_EXP_PROGRAM = _final_exp_program()
+
+
+
+
+def _fq12_operand(v: jnp.ndarray, t) -> jnp.ndarray:
+    """Operand transform ``t`` (id / conj / frob1 / frob2) of ``v`` —
+    :func:`fq12_frobenius` with the power chosen at run time."""
+    odd = jnp.stack([v[..., 0, :], LF.neg(v[..., 1, :])], axis=-2)
+    frob = T.fq2_mul(jnp.where(t == _T_FROB1, odd, v),
+                     jnp.where(t == _T_FROB1, _GAMMA[1], _GAMMA[2]))
+    return jnp.where(t >= _T_FROB1, frob,
+                     jnp.where(t == _T_CONJ, T.fq12_conj(v), v))
 
 
 def final_exponentiation_cubed(f: jnp.ndarray) -> jnp.ndarray:
-    """f^(3·(q¹²−1)/r): easy part, then HHT hard part ×3 (docstring above)."""
-    # Easy: f^(q⁶−1) then ^(q²+1).
-    m = T.fq12_mul(T.fq12_conj(f), fq12_inv(f))
-    m = T.fq12_mul(fq12_frobenius(m, 2), m)
-    # Hard ×3: (u−1)²·(u+p)·(u²+p²−1) + 3.
-    m1 = T.fq12_mul(_pow_u(m), T.fq12_conj(m))            # m^(u−1)
-    k2 = T.fq12_mul(_pow_u(m1), T.fq12_conj(m1))          # ^(u−1)
-    k3 = T.fq12_mul(_pow_u(k2), fq12_frobenius(k2, 1))    # ^(u+p)
-    k4 = T.fq12_mul(T.fq12_mul(_pow_u(_pow_u(k3)), fq12_frobenius(k3, 2)),
-                    T.fq12_conj(k3))                      # ^(u²+p²−1)
-    return T.fq12_mul(k4, T.fq12_mul(T.fq12_sqr(m), m))
+    """f^(3·(q¹²−1)/r): easy part, then HHT hard part ×3 — the step
+    table above, ending in register X."""
+    one = jnp.broadcast_to(jnp.asarray(T.FQ12_ONE_LIMBS), f.shape)
+    regs = jnp.stack([f, fq12_inv(f), one, one, one, one, one])
+
+    def step(regs, row):
+        op, dst, ra, ta, rb, tb = (row[k] for k in range(6))
+        va = _fq12_operand(jax.lax.dynamic_index_in_dim(regs, ra, 0, False),
+                           ta)
+        vb = _fq12_operand(jax.lax.dynamic_index_in_dim(regs, rb, 0, False),
+                           tb)
+        res = jnp.where(op == _OP_COPY, va, T.fq12_mul(va, vb))
+        return jax.lax.dynamic_update_index_in_dim(regs, res, dst, 0), None
+
+    rows = jnp.asarray(FINAL_EXP_PROGRAM.reshape(-1, 6))
+    regs, _ = jax.lax.scan(step, regs, rows)
+    return regs[_R_X]
 
 
 def fq12_is_one(f: jnp.ndarray) -> jnp.ndarray:

@@ -22,7 +22,7 @@ Two engines share the columnar state:
   vote scatters, the new justified balances, and n-node-sized masks, and
   pulls back three small node columns (weight/best-child/best-descendant)
   plus nothing else.  Like the epoch sweep, the kernel traces and runs
-  inside a local ``jax.experimental.enable_x64()`` so uint64 balance
+  inside a local ``jax.enable_x64(True)`` so uint64 balance
   arithmetic matches numpy bit-for-bit.
 
 Engine selection: ``LIGHTHOUSE_TPU_FORKCHOICE_JIT=1`` forces the jitted
@@ -200,7 +200,6 @@ def _get_kernel(n_pad: int, nv_pad: int):
     if fn is not None:
         return fn
     import jax
-    from jax.experimental import enable_x64
 
     local_delta, propagate = _kernel_parts(n_pad)
 
@@ -210,7 +209,7 @@ def _get_kernel(n_pad: int, nv_pad: int):
     jitted = jax.jit(fused)
 
     def call(*args):
-        with enable_x64():
+        with jax.enable_x64(True):
             return jitted(*args)
 
     _KERNELS[key] = call
@@ -231,7 +230,6 @@ def _get_mesh_kernel(n_pad: int, nv_pad: int):
     if fn is not None:
         return fn
     import jax
-    from jax.experimental import enable_x64
     from jax.sharding import PartitionSpec as P
 
     local_delta, propagate = _kernel_parts(n_pad)
@@ -248,7 +246,7 @@ def _get_mesh_kernel(n_pad: int, nv_pad: int):
         out_specs=(P(), P(), P(), P()))
 
     def call(*args):
-        with enable_x64():
+        with jax.enable_x64(True):
             return prog(*args)
 
     _MESH_KERNELS[key] = call
@@ -261,7 +259,6 @@ def _get_scatter(nv_pad: int, k_pad: int):
     if fn is not None:
         return fn
     import jax
-    from jax.experimental import enable_x64
 
     def scatter(nxt, idx, val):
         return nxt.at[idx].set(val)
@@ -269,7 +266,7 @@ def _get_scatter(nv_pad: int, k_pad: int):
     jitted = jax.jit(scatter, donate_argnums=())
 
     def call(*args):
-        with enable_x64():
+        with jax.enable_x64(True):
             return jitted(*args)
 
     _SCATTERS[key] = call
@@ -283,13 +280,13 @@ class _DeviceMirror:
 
     def __init__(self, votes: VoteBuffer, old_balances: np.ndarray,
                  n_nodes: int):
-        from jax.experimental import enable_x64
+        import jax
         from ..common.device_ledger import LEDGER
         from ..parallel.mesh import mesh_put
 
         self.nv_pad = _bucket(max(len(votes), 1))
         self.n_pad = _bucket(max(n_nodes, 1))
-        with enable_x64():
+        with jax.enable_x64(True):
             cur = np.full(self.nv_pad, -1, np.int32)
             cur[:len(votes)] = votes.current
             nxt = np.full(self.nv_pad, -1, np.int32)
@@ -331,7 +328,7 @@ class _DeviceMirror:
     def scatter_votes(self, wv: np.ndarray, wn: np.ndarray) -> None:
         if wv.shape[0] == 0:
             return
-        from jax.experimental import enable_x64
+        import jax
         from ..parallel.mesh import mesh_put
         k_pad = _bucket(wv.shape[0], floor=8)
         idx = np.empty(k_pad, np.int32)
@@ -340,7 +337,7 @@ class _DeviceMirror:
         idx[wv.shape[0]:] = wv[0]  # duplicate-set padding: idempotent
         val[:wn.shape[0]] = wn
         val[wn.shape[0]:] = wn[0]
-        with enable_x64():
+        with jax.enable_x64(True):
             self.nxt = _get_scatter(self.nv_pad, k_pad)(
                 self.nxt, mesh_put("fc_dirty", idx),
                 mesh_put("fc_dirty", val))
@@ -349,7 +346,7 @@ class _DeviceMirror:
     def push_topology(self, cols: NodeColumns, version: int) -> None:
         if self.topo_version == version and self.parent is not None:
             return
-        from jax.experimental import enable_x64
+        import jax
         from ..parallel.mesh import mesh_put
         n = cols.n
         parent = np.full(self.n_pad, -1, np.int32)
@@ -358,7 +355,7 @@ class _DeviceMirror:
         depth[:n] = cols.depth[:n]
         weight = np.zeros(self.n_pad, np.int64)
         weight[:n] = cols.weight[:n]
-        with enable_x64():
+        with jax.enable_x64(True):
             self.parent = mesh_put("fc_topology", parent)
             self.depth = mesh_put("fc_topology", depth)
             self.weight = mesh_put("fc_topology", weight)
@@ -490,12 +487,12 @@ class DeviceProtoArrayForkChoice:
             if self._pending_new_b is not None and self._mirror is not None:
                 # compute_deltas without an intervening apply: the host
                 # still moves votes/balances — replicate the device move.
-                from jax.experimental import enable_x64
+                import jax
                 from ..parallel.mesh import mesh_put
                 nb = np.zeros(self._mirror.nv_pad, np.int64)
                 nb[:self._pending_new_b.shape[0]] = \
                     self._pending_new_b.astype(np.int64)
-                with enable_x64():
+                with jax.enable_x64(True):
                     self._mirror.old_b = mesh_put("fc_votes", nb)
                     self._mirror.cur = self._mirror.nxt
                 self._mirror._note_residency()
@@ -556,11 +553,11 @@ class DeviceProtoArrayForkChoice:
             np.asarray(new_balances, np.uint64), self.cols.n)
         if self._mirror is not None \
                 and self._mirror.fits(self.votes_store, 1):
-            from jax.experimental import enable_x64
+            import jax
             from ..parallel.mesh import mesh_put
             nb = np.zeros(self._mirror.nv_pad, np.int64)
             nb[:new_b.shape[0]] = new_b.astype(np.int64)
-            with enable_x64():
+            with jax.enable_x64(True):
                 self._mirror.old_b = mesh_put("fc_votes", nb)
                 self._mirror.cur = self._mirror.nxt
             self._mirror._note_residency()
@@ -610,7 +607,7 @@ class DeviceProtoArrayForkChoice:
                    b_score) -> None:
         import time as _time
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        import jax
         from ..common.device_ledger import LEDGER
         from ..parallel import mesh as pmesh
 
@@ -641,7 +638,7 @@ class DeviceProtoArrayForkChoice:
         t_dispatch = _time.perf_counter()
         ndev = pmesh.axis_size()
         use_mesh = ndev > 1 and mir.nv_pad % ndev == 0
-        with enable_x64():
+        with jax.enable_x64(True):
             kernel = (_get_mesh_kernel(n_pad, mir.nv_pad) if use_mesh
                       else _get_kernel(n_pad, mir.nv_pad))
             new_b_dev = pmesh.mesh_put("fc_votes", new_b)
@@ -835,12 +832,12 @@ def warmup(n_nodes: int, n_validators: int) -> None:
     """Pre-compile the fused kernel for the given shape buckets (the
     scripts' ``--warmup`` hook; compiles persist via the common cache)."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    import jax
     from ..parallel import mesh as pmesh
     n_pad = _bucket(n_nodes)
     nv_pad = _bucket(n_validators)
     ndev = pmesh.axis_size()
-    with enable_x64():
+    with jax.enable_x64(True):
         kernel = (_get_mesh_kernel(n_pad, nv_pad)
                   if ndev > 1 and nv_pad % ndev == 0
                   else _get_kernel(n_pad, nv_pad))

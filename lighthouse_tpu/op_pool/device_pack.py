@@ -25,7 +25,7 @@ Entry counts and candidate counts are bucket-padded to the next power
 of two (the ``parallel/bls_shard`` / fork-choice mirror pattern), so
 pool growth re-uses compiled programs instead of recompiling.  Like
 the fork-choice jit engine, the kernel traces inside a scoped
-``jax.experimental.enable_x64()`` (the bitset is uint64, marginals are
+``jax.enable_x64(True)`` (the bitset is uint64, marginals are
 int64) and auto-selects: jitted XLA on a real TPU, an equivalent
 vectorized numpy rounds engine elsewhere (CPU jit is correctness-equal
 but compile-bound at test shapes).  ``LIGHTHOUSE_TPU_DEVICE_PACK=0``
@@ -181,7 +181,6 @@ def _get_pack_kernel(lb: int, b: int, words: int, rounds: int):
         return fn
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     def pack(word, bitmask, wgt, seg, valid):
         covered0 = jnp.zeros(words, jnp.uint64)
@@ -206,7 +205,7 @@ def _get_pack_kernel(lb: int, b: int, words: int, rounds: int):
     jitted = jax.jit(pack)
 
     def call(*args):
-        with enable_x64():
+        with jax.enable_x64(True):
             return jitted(*args)
 
     _KERNELS[key] = call
@@ -218,7 +217,6 @@ def _pack_rounds_jit(flat_e: np.ndarray, flat_w: np.ndarray,
                      limit: int) -> List[int]:
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     from ..common.device_ledger import LEDGER
 
     n = offsets.shape[0] - 1
@@ -240,7 +238,7 @@ def _pack_rounds_jit(flat_e: np.ndarray, flat_w: np.ndarray,
     valid[:L] = True
     t0 = time.perf_counter()
     with LEDGER.attribute("op_pool"):
-        with enable_x64():
+        with jax.enable_x64(True):
             d_word = jnp.asarray(word)        # device-io: op_pool
             d_bit = jnp.asarray(bitmask)      # device-io: op_pool
             d_wgt = jnp.asarray(wgt)          # device-io: op_pool

@@ -18,13 +18,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-# The experimental import with ``check_rep=False`` is the ONE spelling
-# proven on both jax lineages this repo runs under (0.4.x here, newer on
-# the multichip driver) — the same pattern as ``bls_shard``'s
-# ``sharded_g1_sum``.  The 0.5+ top-level ``jax.shard_map`` renamed the
-# kwarg to ``check_vma``, so feature-detecting the import and passing one
-# kwarg name unconditionally breaks on whichever side wasn't tested.
-from jax.experimental.shard_map import shard_map
 
 from ..ops.merkle import merkleize
 from .mesh import BATCH_AXIS, axis_size, batch_sharding, mesh_program
@@ -60,13 +53,13 @@ def sharded_merkle_root(leaves: jnp.ndarray, mesh: Mesh, depth: int) -> jnp.ndar
         # chunk: (local_n, 8) — one whole aligned sub-tree per device.
         return merkleize(chunk, local_depth)[None]  # (1, 8)
 
-    # check_rep=False: the SHA round scan seeds its carry with the constant
+    # check_vma=False: the SHA round scan seeds its carry with the constant
     # IV (unvarying) and folds in the sharded block, which trips the
-    # replication/varying-axes check; semantics are still purely per-shard.
-    roots = shard_map(
+    # varying-axes check; semantics are still purely per-shard.
+    roots = jax.shard_map(
         local_subtree, mesh=mesh,
         in_specs=P(BATCH_AXIS), out_specs=P(BATCH_AXIS),
-        check_rep=False,
+        check_vma=False,
     )(leaves)  # (ndev, 8), sharded — the following gather rides ICI.
 
     return merkleize(roots, depth, base_level=local_depth)

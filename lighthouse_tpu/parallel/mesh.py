@@ -31,10 +31,10 @@ persistent column through the seams here:
   shard for a replicated one).  Attribution: explicit ``subsystem=``
   argument > ambient :meth:`DeviceLedger.attribute` scope > the
   column's registered subsystem.
-- :func:`mesh_program` — the one proven ``shard_map`` spelling
-  (``jax.experimental.shard_map`` + ``check_rep=False``; see
-  merkle_shard's note on why the SHA IV constant trips the replication
-  checker) wrapped in ``jax.jit``.
+- :func:`mesh_program` — the one ``shard_map`` spelling
+  (``jax.shard_map`` + ``check_vma=False``; see merkle_shard's note on
+  why the SHA IV constant trips the varying-axes checker) wrapped in
+  ``jax.jit``.
 
 The graftlint ``mesh-residency`` checker enforces the contract from the
 other side: raw ``jax.device_put`` in the five persistent-residency
@@ -49,7 +49,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.device_ledger import LEDGER, SUBSYSTEMS
@@ -304,18 +303,18 @@ def mesh_gather(arr, subsystem: Optional[str] = None,
 def mesh_program(fn, *, mesh: Optional[Mesh] = None, in_specs,
                  out_specs, **jit_kwargs):
     """The standard sharded-program spelling: ``jax.jit`` around
-    ``shard_map(fn, ..., check_rep=False)``.
+    ``jax.shard_map(fn, ..., check_vma=False)``.
 
-    ``check_rep=False`` is load-bearing, not a shrug: every kernel here
+    ``check_vma=False`` is load-bearing, not a shrug: every kernel here
     closes over replicated constant tables (the SHA-256 IV/round
-    constants, curve generators), and the replication checker flags
+    constants, curve generators), and the varying-axes checker flags
     those as possibly-divergent per-shard values; see
     ``parallel/merkle_shard.py`` for the full note.  Centralizing the
     spelling keeps the jax-hygiene checker's contract in ONE place.
     """
     mesh = get_mesh() if mesh is None else mesh
-    mapped = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     return jax.jit(mapped, **jit_kwargs)
 
 
@@ -367,6 +366,10 @@ register_column("slasher_groups", P(), subsystem="slasher",
 # Fq12 pairing partials and the mont-mul selection matrices replicate.
 register_column("bls_sets", P(BATCH_AXIS), subsystem="bls",
                 doc="marshalled signature-set limb blocks (n_sets, ...)")
+register_column("bls_lanes", P(None, BATCH_AXIS), subsystem="bls",
+                doc="Pallas-layout set planes (rows, chunks · 128 lanes)")
+register_column("bls_table", P(), subsystem="bls",
+                doc="device pubkey table (64, capacity), replicated")
 register_column("fq12_partials", P(), subsystem="bls",
                 doc="per-shard Fq12 pairing partials (replicated)")
 register_column("selection_matrices", P(), subsystem="bls",

@@ -9,7 +9,7 @@ every validator — the device-side twin of
 Exactness: the sweep is u64 arithmetic with spec wrap/floor semantics, and
 this process runs without global ``jax_enable_x64`` (the crypto kernels are
 explicit-dtype 32-bit limb code).  The kernel therefore traces AND executes
-inside ``jax.experimental.enable_x64()``, where jnp uint64 matches numpy
+inside ``jax.enable_x64(True)``, where jnp uint64 matches numpy
 uint64 bit-for-bit (asserted against the numpy sweep in tests).  Compiles
 land in the persistent compile cache (``common/compile_cache``) like every
 other kernel; :func:`warmup` pre-lowers a given registry size so the first
@@ -40,7 +40,6 @@ def _get_kernel():
     try:
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
     except Exception:  # pragma: no cover - jax always present in-tree
         return None
 
@@ -96,7 +95,7 @@ def _get_kernel():
     jitted = jax.jit(sweep)
 
     def call(*args):
-        with enable_x64():
+        with jax.enable_x64(True):
             return jitted(*args)
 
     _KERNEL = call

@@ -328,7 +328,6 @@ def _repack_rebuild(col_dev, w: int):
     convention, `per_epoch_device`)."""
     global _repack_levels_jit
     import jax
-    from jax.experimental import enable_x64
     from ..ops.merkle_kernel import _levels_body, _use_pallas
 
     if _repack_levels_jit is None:
@@ -337,7 +336,7 @@ def _repack_rebuild(col_dev, w: int):
                                 use_kernel=use_kernel)
         _repack_levels_jit = jax.jit(body,
                                      static_argnames=("w", "use_kernel"))
-    with enable_x64():
+    with jax.enable_x64(True):
         return _repack_levels_jit(col_dev, w=w, use_kernel=_use_pallas())
 
 

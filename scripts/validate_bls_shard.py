@@ -44,8 +44,7 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 from lighthouse_tpu.common.compile_cache import enable as _cache_enable  # noqa: E402
 
-_cache_enable(os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), ".jax_cache"))
+_cache_enable()
 
 import jax.numpy as jnp  # noqa: E402
 

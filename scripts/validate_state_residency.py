@@ -23,8 +23,8 @@ transfer between processes with different XLA flags.  To warm the same
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python scripts/validate_state_residency.py --warmup ...
 
-(this script sets ``jax_compilation_cache_dir`` to the repo's
-``.jax_cache`` itself, like conftest).  With ``--device`` the attached
+(this script enables the one compile-cache directory itself, like
+conftest: ``JAX_COMPILATION_CACHE_DIR`` when set, else ``.jax_cache``).  With ``--device`` the attached
 backend is kept instead (real-TPU residency, Pallas hash kernels).
 
 Usage:
@@ -46,11 +46,8 @@ def _configure_jax(device: bool) -> None:
     import jax
     if not device:
         jax.config.update("jax_platforms", "cpu")
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    from lighthouse_tpu.common.compile_cache import enable
+    enable()
 
 
 def _mk_state(n: int, seed: int):

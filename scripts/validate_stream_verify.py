@@ -67,15 +67,8 @@ import time
 
 def _configure_jax() -> None:
     """Repo-standard persistent compile cache (device backends only)."""
-    try:
-        import jax
-    except Exception:
-        return
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    from lighthouse_tpu.common.compile_cache import enable
+    enable()
 
 
 def _parse_burst(spec: str):

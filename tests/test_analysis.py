@@ -101,13 +101,17 @@ def test_knob_clamp_warns(monkeypatch):
         assert knobs.knob_int("LIGHTHOUSE_TPU_BREAKER_N") == 1
 
 
-def test_jax_cache_registry_default_is_usable():
-    """The registry default is the REAL repo path, not the README's
-    '<repo>' placeholder (which os.makedirs would create verbatim)."""
-    assert knobs.knob_str("LH_TPU_JAX_CACHE") == \
-        os.path.join(REPO, ".jax_cache")
-    assert "<repo>" not in knobs.knob_str("LH_TPU_JAX_CACHE")
-    assert "`<repo>/.jax_cache`" in knobs.render_knob_table()
+def test_jax_cache_registry_default_is_usable(monkeypatch):
+    """One cache directory, one name: JAX_COMPILATION_CACHE_DIR when set,
+    else the REAL repo path (not a '<repo>' placeholder, which
+    os.makedirs would create verbatim)."""
+    from lighthouse_tpu.common import compile_cache as CC
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert CC.default_dir() == os.path.join(REPO, ".jax_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/srv/jax-cache")
+    assert CC.default_dir() == "/srv/jax-cache"
+    assert "`JAX_COMPILATION_CACHE_DIR`" in knobs.render_knob_table()
 
 
 def test_knob_tribool(monkeypatch):
@@ -322,40 +326,59 @@ def test_jax_checker_flags_global_x64():
             jax.config.update("jax_enable_x64", True)
     """})
     assert details(found) == ["enable-x64-config:f"]
-    assert "enable_x64()" in found[0].hint
+    assert "jax.enable_x64(True)" in found[0].hint
+
+
+def test_jax_checker_flags_experimental_x64():
+    found = run_checker("jax-hygiene", {"lighthouse_tpu/x.py": """
+        import jax
+        from jax.experimental import enable_x64
+        def f():
+            with jax.experimental.enable_x64():
+                pass
+    """})
+    assert sorted(details(found)) == ["enable-x64-import",
+                                      "enable-x64-spelling:f"]
 
 
 def test_jax_checker_flags_shard_map_spellings():
     found = run_checker("jax-hygiene", {"lighthouse_tpu/x.py": """
-        from jax import shard_map
+        from jax.experimental.shard_map import shard_map
 
         def f(mesh):
-            return shard_map(lambda x: x, mesh=mesh)
+            return shard_map(lambda x: x, mesh=mesh, check_rep=False)
     """})
     d = details(found)
     assert "shard-map-import" in d
-    assert "shard-map-check-rep:f" in d
+    assert "shard-map-spelling:f" in d
 
 
 def test_jax_checker_wrong_spelling_is_one_finding():
-    """jax.shard_map(...) without check_rep is ONE defect (the
-    spelling) — not a second stale-able check-rep waiver key."""
+    """shard_map(...) through the old module is ONE defect (the
+    spelling) — not a second stale-able check-vma waiver key."""
+    found = run_checker("jax-hygiene", {"lighthouse_tpu/x.py": """
+        import jax.experimental.shard_map as sm
+        def f(mesh):
+            return sm.shard_map(lambda x: x, mesh=mesh)
+    """})
+    assert details(found) == ["shard-map-spelling:f"]
+
+
+def test_jax_checker_flags_missing_check_vma():
     found = run_checker("jax-hygiene", {"lighthouse_tpu/x.py": """
         import jax
         def f(mesh):
             return jax.shard_map(lambda x: x, mesh=mesh)
     """})
-    assert details(found) == ["shard-map-spelling:f"]
+    assert details(found) == ["shard-map-check-vma:f"]
 
 
-def test_jax_checker_passes_proven_spellings():
+def test_jax_checker_passes_installed_spellings():
     found = run_checker("jax-hygiene", {"lighthouse_tpu/x.py": """
         import numpy as np
         import jax
         import jax.numpy as jnp
         from functools import partial
-        from jax.experimental.shard_map import shard_map
-        from jax.experimental import enable_x64
 
         TABLE = np.arange(16)              # numpy at import: fine
 
@@ -364,10 +387,10 @@ def test_jax_checker_passes_proven_spellings():
             return jnp.arange(n) + x       # jnp inside function: fine
 
         def f(mesh, x):
-            with enable_x64():
+            with jax.enable_x64(True):
                 y = jnp.asarray(x)
-            return shard_map(lambda v: v, mesh=mesh,
-                             check_rep=False)(y)
+            return jax.shard_map(lambda v: v, mesh=mesh,
+                                 check_vma=False)(y)
 
         def cache(d):
             jax.config.update("jax_compilation_cache_dir", d)  # not x64

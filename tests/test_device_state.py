@@ -198,7 +198,7 @@ def test_adopted_device_column_roots_without_pull():
     the device array becomes the column, the root re-reduces in HBM, and
     the host twin assigning the same values agrees."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    import jax
 
     host, dev = _twins(48, seed=5)
     host.tree_hash_root(), dev.tree_hash_root()
@@ -206,7 +206,7 @@ def test_adopted_device_column_roots_without_pull():
     n = host.balances.shape[0]
     new = np.random.default_rng(1).integers(
         0, 1 << 40, n).astype(np.uint64)
-    with enable_x64():
+    with jax.enable_x64(True):
         dev_arr = jnp.asarray(new)
     store_column(dev, "balances", dev_arr)
     store_column(host, "balances", new.copy())
@@ -225,13 +225,13 @@ def test_adopted_then_host_write_before_any_root():
     adopted values, so only a full diff can recover them (regression —
     index tracking used to report just the new write's indices)."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    import jax
 
     host, dev = _twins(48, seed=5)
     host.tree_hash_root(), dev.tree_hash_root()
     n = host.balances.shape[0]
     new = np.random.default_rng(1).integers(0, 1 << 40, n).astype(np.uint64)
-    with enable_x64():
+    with jax.enable_x64(True):
         dev_arr = jnp.asarray(new)
     store_column(dev, "balances", dev_arr)   # adopt; no root taken
     store_column(host, "balances", new.copy())
@@ -242,7 +242,7 @@ def test_adopted_then_host_write_before_any_root():
     # Same shape through the touched= seam of store_column.
     dev2_host, dev2 = _twins(48, seed=6)
     dev2_host.tree_hash_root(), dev2.tree_hash_root()
-    with enable_x64():
+    with jax.enable_x64(True):
         arr2 = jnp.asarray(new)
     store_column(dev2, "balances", arr2)
     store_column(dev2_host, "balances", new.copy())

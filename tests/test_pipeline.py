@@ -226,26 +226,30 @@ def test_bls_pipeline_verdicts_bit_identical(monkeypatch):
         assert mono == piped == want
 
 
-def test_donated_entry_points_exist():
-    """The hot-path jits carry buffer donation (marshalled limb arrays
-    and the finalize product are batch-local), while the reusable-input
-    entries stay undonated for profiling/tests."""
+def test_donated_entry_points_exist(monkeypatch):
+    """The hot path's finalize carries buffer donation on TPU (the
+    folded product is batch-local), while the reusable-input entry stays
+    undonated for profiling/tests — and is the one selected off-TPU
+    (donation is a warning-only no-op on CPU, but the intent is
+    explicit)."""
     from lighthouse_tpu.crypto import pairing_kernel as PK
     from lighthouse_tpu.crypto import tpu_backend as TB
 
-    assert TB.fused_pipeline_jit(donate=True) is TB._fused_pipeline_donated
-    assert TB.fused_pipeline_jit(donate=False) is TB._fused_pipeline
-    # off-TPU the dispatcher must select the undonated twin (donation is
-    # a warning-only no-op on CPU, but the intent is explicit)
-    assert TB.fused_pipeline_jit() is TB._fused_pipeline
     assert PK.finalize_kernel_call_donated is not PK.finalize_kernel_call
+    monkeypatch.setattr(PK, "finalize_kernel_call", lambda x: "plain")
+    monkeypatch.setattr(PK, "finalize_kernel_call_donated",
+                        lambda x: "donated")
+    assert TB._finalize(None) == "plain"
+    monkeypatch.setattr(TB, "_use_pallas", lambda: True)
+    assert TB._finalize(None) == "donated"
 
 
-def test_compile_cache_roundtrip(tmp_path):
+def test_compile_cache_roundtrip(tmp_path, monkeypatch):
     """Round trip of the persistent compile cache: a fresh compile lands
-    in the cache dir; after ``jax.clear_caches()`` (a stand-in for a
-    restarted process sharing the dir) the same program compiles WITHOUT
-    adding files — a disk hit, not an XLA recompile."""
+    in the directory JAX_COMPILATION_CACHE_DIR names; after
+    ``jax.clear_caches()`` (a stand-in for a restarted process sharing
+    the dir) the same program compiles WITHOUT adding files — a disk
+    hit, not an XLA recompile."""
     import jax
     import jax.numpy as jnp
 
@@ -253,9 +257,9 @@ def test_compile_cache_roundtrip(tmp_path):
 
     old_dir = jax.config.jax_compilation_cache_dir
     old_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    cache = CC.enable(str(tmp_path), min_compile_time_secs=0.0)
-    if cache is None:
-        pytest.skip("jax build without persistent-cache support")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    cache = CC.enable(min_compile_time_secs=0.0)
+    assert cache == str(tmp_path)
     try:
         def fn(x):
             return (x * jnp.float32(3.0) + jnp.float32(1.5)).sum()
@@ -269,14 +273,7 @@ def test_compile_cache_roundtrip(tmp_path):
         n2 = len(list(tmp_path.iterdir()))
         assert n2 == n1, "second compile missed the persistent cache"
     finally:
-        # re-enable (not just config-update) so the live cache object
-        # points back at the suite's shared directory
-        if old_dir:
-            CC.enable(old_dir, old_min)
-        else:
-            jax.config.update("jax_compilation_cache_dir", old_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", old_min)
+        _restore_cache(old_dir, old_min)
 
 
 def test_warmup_is_graceful_noop_on_cpu():
@@ -293,8 +290,8 @@ def test_warmup_is_graceful_noop_on_cpu():
     assert out.get("compiled") == []
 
 
-def test_cli_warmup_subcommand(capsys, tmp_path):
-    """`lighthouse-tpu warmup` wires the cache flag + warmup API (CPU:
+def test_cli_warmup_subcommand(capsys, tmp_path, monkeypatch):
+    """`lighthouse-tpu warmup` wires the cache rule + warmup API (CPU:
     reports the no-op and the configured cache dir)."""
     import json
 
@@ -302,24 +299,26 @@ def test_cli_warmup_subcommand(capsys, tmp_path):
 
     from lighthouse_tpu.cli import main
 
-    from lighthouse_tpu.common import compile_cache as CC
-
     old_dir = jax.config.jax_compilation_cache_dir
+    old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     try:
-        assert main(["warmup", "--compile-cache", str(tmp_path),
-                     "--shapes", "8x1"]) == 0
+        assert main(["warmup", "--shapes", "8x1"]) == 0
     finally:
-        if old_dir:
-            CC.enable(old_dir)
-        else:
-            jax.config.update("jax_compilation_cache_dir", old_dir)
+        _restore_cache(old_dir, old_min)
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["skipped"] == "cpu"
     assert out["cache_dir"] == str(tmp_path)
-    # a warmup that persists nothing is refused, not silently wasted
-    assert main(["warmup", "--compile-cache", "off"]) == 2
-    refusal = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "error" in refusal
+
+
+def _restore_cache(old_dir, old_min) -> None:
+    """Point the live cache object back at the suite's directory."""
+    import jax
+    from jax._src import compilation_cache as _cc
+
+    jax.config.update("jax_compilation_cache_dir", old_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
+    _cc.reset_cache()
 
 
 def test_pipeline_metrics_instrumented():
