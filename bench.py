@@ -55,11 +55,10 @@ Also measured (BASELINE rows 2-5 + latency tier):
   (6 mainnet-width blobs): device barycentric evaluation + 2 Miller
   lanes per blob + one shared final exponentiation, with per-stage
   timings (``kzg_eval_ms`` / ``kzg_pairing_ms`` / ...).
-- ``stage_overlap_efficiency`` — fraction of BLS host marshalling the
-  staged pipeline hid behind device compute (1.0 = all sub-batch preps
-  after the first ran under an in-flight dispatch), with
-  ``pipeline_dispatches`` / ``pipeline_host_prep_ms`` /
-  ``pipeline_overlap_prep_ms`` carrying the raw decomposition.
+- ``pipeline_host_prep_ms`` / ``pipeline_dispatches`` — host
+  marshalling time and staged sub-batch dispatches per headline BLS
+  batch, read from the cumulative ``bls_pipeline_host_prep_seconds``
+  histogram across the timing loop.
 
 The run needs the chip: when JAX finds no TPU it exits 1 before any row
 runs — no row is ever re-run on the CPU under a device metric name.
@@ -134,6 +133,7 @@ def _emit(row: dict) -> None:
 def _bls_bench() -> dict:
     from lighthouse_tpu.crypto import bls
     from lighthouse_tpu.common import tracing
+    from lighthouse_tpu.common.metrics import REGISTRY
     from lighthouse_tpu.crypto import tpu_backend as TB  # noqa (registers)
     from lighthouse_tpu.crypto.fields import R
 
@@ -166,6 +166,10 @@ def _bls_bench() -> dict:
     if tpu.verify_signature_sets(bad):
         raise RuntimeError("tampered batch accepted")
 
+    # The staged executor's cumulative host-prep histogram, diffed
+    # across the timing loop: marshalling per headline batch.
+    prep = REGISTRY.histogram("bls_pipeline_host_prep_seconds")
+    _b, _c, prep_n0, prep_s0 = prep.snapshot()
     ts = []
     for _ in range(RUNS):
         t0 = time.perf_counter()
@@ -173,9 +177,7 @@ def _bls_bench() -> dict:
             raise RuntimeError("valid batch rejected in timing loop")
         ts.append(time.perf_counter() - t0)
     best = min(ts)
-    # Snapshot the staged-pipeline decomposition of the headline batch
-    # NOW — the single-set / fast-aggregate rows below overwrite it.
-    pipeline_stats = tracing.stage_split("pipeline")
+    _b, _c, prep_n1, prep_s1 = prep.snapshot()
 
     # Latency tier: one single-key set (gossip proposer-signature shape).
     single = [bls.SignatureSet(sks[0].sign(msgs[0]), [pks[0]], msgs[0])]
@@ -235,14 +237,11 @@ def _bls_bench() -> dict:
         "bls_setup_s": round(setup_s, 1),
         **_breaker_attribution("bls", breaker_mark),
     }
-    if pipeline_stats:
+    if prep_n1 > prep_n0:
         out.update({
-            "pipeline_dispatches": pipeline_stats.get("dispatches"),
-            "pipeline_host_prep_ms": pipeline_stats.get("host_prep_ms"),
-            "pipeline_overlap_prep_ms":
-                pipeline_stats.get("overlap_prep_ms"),
-            "stage_overlap_efficiency":
-                pipeline_stats.get("overlap_efficiency"),
+            "pipeline_dispatches": (prep_n1 - prep_n0) / RUNS,
+            "pipeline_host_prep_ms":
+                round((prep_s1 - prep_s0) * 1e3 / RUNS, 1),
         })
     return out
 
@@ -1097,8 +1096,8 @@ def _stream_verify_bench() -> dict:
     return {
         "stream_ledger_device_dispatches":
             int(_bls["dispatches"] - _base["dispatches"]),
-        "stream_ledger_device_verify_total_ms":
-            round(_bls["device_ms"] - _base["device_ms"], 2),
+        "stream_ledger_dispatch_wall_total_ms":
+            round(_bls["dispatch_wall_ms"] - _base["dispatch_wall_ms"], 2),
         "stream_ledger_h2d_bytes":
             int(_bls["h2d_bytes"] - _base["h2d_bytes"]),
         "stream_messages": out["messages"],

@@ -412,6 +412,7 @@ class ResilienceEnvelope:
         # Device-ledger attribution: every envelope family is a bls or a
         # kzg dispatch stream (the two device verify families).
         self._ledger_subsystem = "kzg" if "kzg" in name else "bls"
+        self._host_span = f"{self._ledger_subsystem}.host_verify"
         self._m_faults = REGISTRY.counter(
             f"{name}_device_faults_total", "device dispatch failures")
         self._m_fallbacks = REGISTRY.counter(
@@ -423,9 +424,10 @@ class ResilienceEnvelope:
 
     def _attempt(self, fn: Callable, args: tuple,
                  deadline_s: Optional[float]):
-        """One device attempt.  The fault-injection site fires INSIDE the
-        deadline scope, so an injected stall longer than the deadline is
-        observed as :class:`DeadlineExceeded` — the blowout scenario."""
+        """One device attempt; returns ``(result, on_host)``.  The
+        fault-injection site fires INSIDE the deadline scope, so an
+        injected stall longer than the deadline is observed as
+        :class:`DeadlineExceeded` — the blowout scenario."""
         if self._faults is not None:
             inner = self._faults.wrap(self._fault_site, fn)
         else:
@@ -437,9 +439,14 @@ class ResilienceEnvelope:
         # enveloped call counts twice.  Wrap the FN, not the call site —
         # under a deadline the watchdog pool runs it on another thread
         # and the suppression flag is thread-local.
+        # The fn also reports whether the device path served the call
+        # on the host (the backend's fast path): the ledger's host-route
+        # mark is thread-local too, so it is read where the fn ran.
         def guarded(*a):
             with LEDGER.suppress_dispatch():
-                return inner(*a)
+                routes = LEDGER.host_routes()
+                out = inner(*a)
+                return out, LEDGER.host_routes() != routes
 
         if deadline_s is None:
             return guarded(*args)
@@ -456,15 +463,28 @@ class ResilienceEnvelope:
         ``probe`` / ``host``.  With no ``host_fn`` a terminal device
         failure re-raises (callers that have no degraded mode keep their
         error semantics)."""
+        out, path, _on_host = self.call_routed(
+            device_fn, host_fn, args, deadline_s=deadline_s,
+            retries=retries)
+        return out, path
+
+    def call_routed(self, device_fn: Callable, host_fn: Optional[Callable],
+                    args: tuple = (), *, deadline_s=False,
+                    retries: Optional[int] = None
+                    ) -> Tuple[object, str, bool]:
+        """:meth:`call` that also says whether the host computed the
+        result: the fallback, or ``device_fn`` taking the backend's host
+        fast path (:meth:`DeviceLedger.note_host_route`).  The path
+        label of such a fast-path call stays ``device``."""
         with TRACER.span(f"{self.name}_envelope",
                          cat="verification_service") as sp:
-            out, path = self._call_inner(device_fn, host_fn, args,
-                                         deadline_s, retries)
+            out, path, on_host = self._call_inner(
+                device_fn, host_fn, args, deadline_s, retries)
             sp.set(path=path)
-            return out, path
+            return out, path, on_host
 
     def _call_inner(self, device_fn, host_fn, args, deadline_s,
-                    retries) -> Tuple[object, str]:
+                    retries) -> Tuple[object, str, bool]:
         if deadline_s is False:
             deadline_s = self.deadline_s
         if retries is None:
@@ -479,7 +499,8 @@ class ResilienceEnvelope:
             for i in range(attempts):
                 t0 = self._clock()
                 try:
-                    out = self._attempt(device_fn, args, deadline_s)
+                    out, on_host = self._attempt(device_fn, args,
+                                                 deadline_s)
                     self.last_attempt_s = self._clock() - t0
                 except Exception as e:  # noqa: BLE001
                     if self.passthrough and isinstance(e, self.passthrough):
@@ -503,21 +524,25 @@ class ResilienceEnvelope:
                     self.breaker.record(True, probe=probe)
                     self._bump("device_ok")
                     # Ledger seam: one successful device dispatch + its
-                    # verify wall time (host fallbacks don't count —
-                    # the ledger answers "what ran on the device").
-                    LEDGER.note_dispatch(self._ledger_subsystem,
-                                         self.last_attempt_s * 1e3)
+                    # wall time (host fallbacks and the backend's host
+                    # fast path don't count — the ledger answers "what
+                    # ran on the device").
+                    if not on_host:
+                        LEDGER.note_dispatch(self._ledger_subsystem,
+                                             self.last_attempt_s * 1e3)
                     return out, ("probe" if probe
-                                 else "device_retry" if i else "device")
+                                 else "device_retry" if i
+                                 else "device"), on_host
         if host_fn is None:
             raise last if last is not None else RuntimeError(
                 f"{self.name}: no host fallback")
         self._bump("host_fallbacks")
         self._m_fallbacks.inc()
         t0 = self._clock()
-        out = host_fn(*args)
+        with TRACER.span(self._host_span, route=route):
+            out = host_fn(*args)
         self.last_attempt_s = self._clock() - t0
-        return out, "host"
+        return out, "host", True
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -645,8 +670,12 @@ class VerificationService:
         self._ewma_dispatch_s: Optional[float] = None
         self.latencies: Deque[float] = deque(maxlen=8192)
         self.batch_sizes: Deque[int] = deque(maxlen=8192)
+        # host_verified_sets: sets whose verdict the host computed (the
+        # backend's fast path or the envelope's fallback) — the share of
+        # the load the chip is not serving.
         self.counters = {"submitted": 0, "verified": 0, "rejected": 0,
                          "shed": 0, "dispatches": 0, "splits": 0,
+                         "host_verified_sets": 0,
                          "slo_violations": 0, "kzg_batches": 0,
                          "kzg_blobs": 0}
         self.pipeline_stats = {"items": 0, "fallbacks": 0}
@@ -656,6 +685,9 @@ class VerificationService:
             labelnames=_LATENCY_LABELS)
         self._m_shed = REGISTRY.counter(
             "stream_verify_shed_total", "messages shed under overload")
+        self._m_host_verified = REGISTRY.counter(
+            "stream_verify_host_verified_sets_total",
+            "signature sets whose verdict the host computed")
         # Service-LOCAL record-time latency aggregate (unregistered —
         # the process-global family above is shared by every service in
         # the process, so a per-chain SLO feed would mix other nodes'
@@ -945,7 +977,8 @@ class VerificationService:
             _sp.set(queue_wait_ms=round(
                 (t0 - min(s.enqueued for s in subs)) * 1e3, 2))
         try:
-            ok, path = self.envelope.call(device, host, (sets,))
+            ok, path, on_host = self.envelope.call_routed(
+                device, host, (sets,))
         except Exception:  # noqa: BLE001 — even a raising HOST path must
             # complete every message (False), never leak into the staged
             # executor's retry (which would double-fire callbacks).
@@ -967,6 +1000,8 @@ class VerificationService:
             self._ewma_dispatch_s = (
                 sample if self._ewma_dispatch_s is None
                 else 0.3 * sample + 0.7 * self._ewma_dispatch_s)
+        if on_host:
+            self._count_host_verified(len(sets))
         observe("stream_verify_dispatch_seconds", dt)
         _sp.set(path=path, verdict=bool(ok))
         if ok or len(subs) == 1:
@@ -977,13 +1012,23 @@ class VerificationService:
         # one junk signature cannot censor the batch (`batch.rs:203`).
         with self._lock:
             self.counters["splits"] += 1
-        for s in subs:
-            try:
-                ok_i, path_i = self.envelope.call(device, host, (s.sets,))
-            except Exception:  # noqa: BLE001
-                ok_i, path_i = False, "error"
-            self._complete(s, bool(ok_i), path_i)
+        with TRACER.span("verify_split", cat="verification_service",
+                         batch=len(subs)):
+            for s in subs:
+                try:
+                    ok_i, path_i, on_host = self.envelope.call_routed(
+                        device, host, (s.sets,))
+                except Exception:  # noqa: BLE001
+                    ok_i, path_i, on_host = False, "error", False
+                if on_host:
+                    self._count_host_verified(len(s.sets))
+                self._complete(s, bool(ok_i), path_i)
         return len(subs)
+
+    def _count_host_verified(self, n: int) -> None:
+        with self._lock:
+            self.counters["host_verified_sets"] += n
+        self._m_host_verified.inc(n)
 
     def _complete(self, sub: _Submission, ok: bool, path: str) -> None:
         with self._lock:

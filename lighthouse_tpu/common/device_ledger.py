@@ -16,8 +16,9 @@ attributed by the fixed :data:`SUBSYSTEMS` enum:
   thread-local context the materialize/scatter/pull seams set), so the
   legacy ``RESIDENCY_STATS`` surface becomes a ledger-backed view and
   every existing caller keeps working.
-- **dispatches** — device dispatch counts + device-verify wall time,
-  fed from the existing seams: the verification-service resilience
+- **dispatches** — device dispatch counts + their host-clock wall time
+  (``dispatch_wall_ms``: submit to verdict, host work included — not a
+  device time), fed from the existing seams: the verification-service resilience
   envelopes (stream bls / kzg / global), ``sig_dispatch``'s direct
   host-backend path, and the sharded BLS entry points.
 - **compiles** — per-program compile events from the jax monitoring
@@ -50,7 +51,7 @@ Surfaces:
   ``device_hbm_resident_bytes{subsystem}``,
   ``device_hbm_high_water_bytes{subsystem}``,
   ``device_dispatches_total{subsystem}``,
-  ``device_verify_seconds_total{subsystem}``,
+  ``device_dispatch_wall_seconds_total{subsystem}``,
   ``device_compiles_total{subsystem}``.
 - The ``device_ledger`` tracing stage source (``tracing.stage_split(
   "device_ledger")`` — the bench/scripts read surface), and per-slot
@@ -109,7 +110,7 @@ _TRANSFER_KEYS = ("h2d_bytes", "h2d_ops", "d2h_bytes", "d2h_ops")
 # traffic the HTTP budget view may exclude; the drill never does).
 _SLOT_KEYS = _TRANSFER_KEYS + ("materializes",)
 _COUNTER_KEYS = _TRANSFER_KEYS + (
-    "dispatches", "device_ms", "compiles", "compile_hits",
+    "dispatches", "dispatch_wall_ms", "compiles", "compile_hits",
     "scatters", "rebuilds", "materializes")
 
 # ---------------------------------------------------------------------------
@@ -332,7 +333,7 @@ class DeviceLedger:
 
     def note_dispatch(self, subsystem: str, wall_ms: float,
                       count: int = 1) -> None:
-        """One device dispatch (count) + its device-verify wall time.
+        """One device dispatch (count) + its wall time on the host clock.
 
         No-op inside a :meth:`suppress_dispatch` scope: the resilience
         envelope wraps device paths that ALSO self-account (the kzg
@@ -345,7 +346,7 @@ class DeviceLedger:
         with self._lock:
             row = self._sub[sub]
             row["dispatches"] += int(count)
-            row["device_ms"] += float(wall_ms)
+            row["dispatch_wall_ms"] += float(wall_ms)
 
     @contextmanager
     def suppress_dispatch(self):
@@ -359,6 +360,18 @@ class DeviceLedger:
             yield
         finally:
             self._tls.suppress -= 1
+
+    def note_host_route(self) -> None:
+        """A device verify path just served its call on the host (the
+        backend's small-batch fast path): the enclosing seam (the
+        envelope) must count no device dispatch for it.  Thread-local
+        like :meth:`suppress_dispatch`; read with :meth:`host_routes`
+        before and after the call, on the thread that ran it."""
+        self._tls.host_routes = getattr(self._tls, "host_routes", 0) + 1
+
+    def host_routes(self) -> int:
+        """Host-routed verifies noted on this thread so far."""
+        return getattr(self._tls, "host_routes", 0)
 
     def note_compile(self, subsystem: Optional[str] = None,
                      count: int = 1, key: str = "compiles") -> None:
@@ -537,7 +550,7 @@ class DeviceLedger:
         with self._lock:
             subs = {}
             for s in SUBSYSTEMS:
-                row = {k: (round(v, 3) if k == "device_ms" else int(v))
+                row = {k: (round(v, 3) if k == "dispatch_wall_ms" else int(v))
                        for k, v in self._sub[s].items()}
                 row["resident_bytes"] = self._resident[s]
                 row["hbm_high_water_bytes"] = self._high[s]
@@ -593,12 +606,12 @@ class DeviceLedger:
                     v = int(row[k])
                     if v:
                         out[f"{s}_{k}"] = v
-                if row["device_ms"]:
+                if row["dispatch_wall_ms"]:
                     # key must NOT end in "_ms": record_stages lays
                     # *_ms keys out as phase spans, and this is a
                     # process-lifetime counter, not a decomposition
-                    out[f"{s}_device_verify_ms_total"] = \
-                        round(row["device_ms"], 3)
+                    out[f"{s}_dispatch_wall_ms_total"] = \
+                        round(row["dispatch_wall_ms"], 3)
                 if self._resident[s]:
                     out[f"{s}_resident_bytes"] = self._resident[s]
             return out
@@ -667,8 +680,8 @@ class DeviceLedger:
             "device dispatches by subsystem",
             labelnames=("subsystem",))
         f_verify = REGISTRY.counter(
-            "device_verify_seconds_total",
-            "device-verify wall time by subsystem",
+            "device_dispatch_wall_seconds_total",
+            "host-clock wall time of device dispatches by subsystem",
             labelnames=("subsystem",))
         f_comp = REGISTRY.counter(
             "device_compiles_total",
@@ -689,7 +702,7 @@ class DeviceLedger:
             self._set_child(f_res, (s,), row["resident_bytes"])
             self._set_child(f_high, (s,), row["hbm_high_water_bytes"])
             self._set_child(f_disp, (s,), row["dispatches"])
-            self._set_child(f_verify, (s,), row["device_ms"] / 1e3)
+            self._set_child(f_verify, (s,), row["dispatch_wall_ms"] / 1e3)
             # BOTH monotonic — net recompiles = requests − hits is a
             # query-time derivation, never a decremented counter.
             self._set_child(f_comp, (s,), row["compiles"])

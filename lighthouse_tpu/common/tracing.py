@@ -14,8 +14,25 @@ instrument:
   ``span(..., parent=ctx)`` (the BeaconProcessor worker /
   verification-service pump-thread hops).  A **disabled** tracer is a
   no-op fast path: ``span()`` returns a shared singleton after one
-  attribute check, and every call site that would compute arguments
-  first guards on ``TRACER.enabled``.
+  attribute check and one profiler-session check, and every call site
+  that would compute arguments first guards on ``TRACER.enabled``.
+- **The device trace's clock** — while a JAX profiler session is
+  active (any ``jax.profiler`` session: ``jax.profiler.start_trace`` /
+  ``trace``, or a TensorBoard / XProf capture against
+  ``jax.profiler.start_server``), every span also opens a
+  ``jax.profiler.TraceAnnotation`` named ``lh.<span name>`` (its
+  creation-time attributes become the event's stats), whether or not
+  the slot ring below is on.  The profiler writes host and device events
+  on one clock, so the node's spans lie against the chip's busy time in
+  the ``.xplane.pb`` with no offset arithmetic; ``python -m
+  lighthouse_tpu.common.profile_spans <log dir>`` charges the device's
+  idle time to them.
+  Names are dotted by layer: ``verify_dispatch``, ``verify_split``,
+  ``bls.host_verify``, ``bls.verdict_sync``, ``<executor>.prep`` /
+  ``.stage`` / ``.dispatch`` (the staged executors), ``state_root`` and
+  ``state_root.<registry|packed|vectors|small|fold>``, ``merkle.prep`` /
+  ``merkle.scatter``.  JAX is never imported here: the annotation class
+  is picked up once the process has imported ``jax.profiler``.
 - **Slot traces** — every completed span lands in the per-slot trace of
   its resolved slot (explicit argument > parent's slot > the ambient
   slot the chain sets from ``per_slot_task``).  A ring buffer keeps the
@@ -37,7 +54,7 @@ instrument:
 Knobs:
 
 ====================================  ======================================
-``LIGHTHOUSE_TPU_TRACE``              ``1`` enables tracing at import
+``LIGHTHOUSE_TPU_TRACE``              ``1`` enables the slot ring at import
 ``LIGHTHOUSE_TPU_TRACE_RING``         slot traces kept (default 64)
 ====================================  ======================================
 
@@ -49,6 +66,7 @@ Surfaced by ``/lighthouse/tracing/slots`` +
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -105,13 +123,50 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+# Prefix of every span's name in a JAX profiler trace.
+PROFILE_PREFIX = "lh."
+
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is
+    active, else None.  Never imports JAX: a process that has not
+    imported ``jax.profiler`` has no session to annotate."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        mod = sys.modules.get("jax.profiler")
+        if mod is None:
+            return None
+        ann = _ANNOTATION = mod.TraceAnnotation
+    return ann if ann.is_enabled() else None
+
+
+class _ProfiledSpan(_NoopSpan):
+    """A span seen only by the profiler (the slot ring is off): the
+    ``lh.`` annotation behind the no-op's call surface."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
 
 class Span:
     """A live span (context manager).  Entering pushes it on the
     thread-local stack; exiting records it into its slot's trace."""
 
     __slots__ = ("_tracer", "name", "cat", "slot", "attrs", "span_id",
-                 "parent_id", "t0", "_entered")
+                 "parent_id", "t0", "_entered", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, slot: int,
                  parent_id: int, attrs: dict):
@@ -124,6 +179,7 @@ class Span:
         self.span_id = next(tracer._ids)
         self.t0 = 0.0
         self._entered = False
+        self._ann = None
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -132,6 +188,10 @@ class Span:
         return SpanContext(self.span_id, self.slot)
 
     def __enter__(self) -> "Span":
+        ann = _profiler_annotation()
+        if ann is not None:
+            self._ann = ann(PROFILE_PREFIX + self.name, **self.attrs)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         self._tracer._stack().append(self)
         self._entered = True
@@ -139,6 +199,9 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         stack = self._tracer._stack()
         if self._entered and stack and stack[-1] is self:
             stack.pop()
@@ -218,9 +281,14 @@ class Tracer:
              parent: Optional[SpanContext] = None, **attrs):
         """Open a span.  ``parent`` (a :class:`SpanContext`) adopts a
         span captured on another thread; otherwise the parent is the
-        thread's innermost open span."""
+        thread's innermost open span.  With the ring off, a span is the
+        shared no-op unless a profiler session is active (module
+        docstring)."""
         if not self.enabled:
-            return _NOOP
+            ann = _profiler_annotation()
+            if ann is None:
+                return _NOOP
+            return _ProfiledSpan(ann(PROFILE_PREFIX + name, **attrs))
         stack = self._stack()
         top = stack[-1] if stack else None
         if parent is not None:
@@ -552,11 +620,6 @@ def _src_residency() -> dict:
     return RESIDENCY_STATS
 
 
-def _src_pipeline() -> dict:
-    from ..crypto.tpu_backend import LAST_PIPELINE_STATS
-    return LAST_PIPELINE_STATS
-
-
 def _src_materialize() -> dict:
     from ..types.device_state import LAST_MATERIALIZE_STATS
     return LAST_MATERIALIZE_STATS
@@ -591,7 +654,6 @@ _STAGE_SOURCES: Dict[str, Callable[[], dict]] = {
     "kzg": _src_kzg,
     "bls_kernels": _src_bls_kernels,
     "residency": _src_residency,
-    "pipeline": _src_pipeline,
     "materialize": _src_materialize,
     "block_sigs": _src_block_sigs,
     "device_ledger": _src_device_ledger,

@@ -482,11 +482,6 @@ def _marshal_group(entries, rand_fn):
     return _cells(C, *planes), K, bool(sigmask.any())
 
 
-# Stats of the most recent pipelined dispatch, surfaced by bench.py
-# (``stage_overlap_efficiency`` et al).
-LAST_PIPELINE_STATS: dict = {}
-
-
 def _pipeline_sets() -> int:
     """Sub-batch size (sets per device dispatch) for the staged
     pipeline.  0 disables sub-batching — one monolithic marshal +
@@ -549,8 +544,8 @@ def _dispatch_pallas(entries, rand_fn) -> bool:
     SSWU kernel — no host curve math at all."""
     import time
 
-    from . import pairing_kernel as PK
     from ..common.device_ledger import LEDGER
+    from ..common.tracing import TRACER
     from ..parallel.pipeline import StagedExecutor
 
     t0 = time.perf_counter()
@@ -569,18 +564,12 @@ def _dispatch_pallas(entries, rand_fn) -> bool:
     prod = _fold_blocks([r[0] for r in results])
     bads = [b for r in results for b in r[1]]
     ok = _finalize(prod)
-    verdict = bool(_combine_verdict(ok, jnp.stack(bads)))
+    with TRACER.span("bls.verdict_sync"):
+        verdict = bool(_combine_verdict(ok, jnp.stack(bads)))
     # Self-accounted like the XLA direct path (suppressed, and counted
     # once by the envelope, when a resilience envelope wraps the call).
     LEDGER.note_dispatch("bls", (time.perf_counter() - t0) * 1e3)
     LEDGER.note_transfer("d2h", 1, subsystem="bls")
-    eff = ex.overlap_efficiency()
-    LAST_PIPELINE_STATS.update(
-        dispatches=len(work),
-        staging_fallbacks=ex.stats["fallbacks"],
-        host_prep_ms=round(ex.stats["host_prep_s"] * 1e3, 1),
-        overlap_prep_ms=round(ex.stats["overlap_prep_s"] * 1e3, 1),
-        overlap_efficiency=None if eff is None else round(eff, 3))
     return verdict
 
 
@@ -915,6 +904,19 @@ def _host_fast(n_sets: int) -> bool:
     return native.ready()  # honors the NO_NATIVE kill-switch
 
 
+def _host_verify(method: str, *args) -> bool:
+    """The host fast path: ``method`` of the python backend (native
+    pairing) under the ``bls.host_verify`` span, noted to the device
+    ledger so an enclosing envelope counts no device dispatch."""
+    from .bls import _BACKENDS
+    from ..common.device_ledger import LEDGER
+    from ..common.tracing import TRACER
+    with TRACER.span("bls.host_verify", route="fast_path"):
+        ok = getattr(_BACKENDS["python"], method)(*args)
+    LEDGER.note_host_route()
+    return ok
+
+
 class TpuBackend:
     """Device-batched verification registered as ``tpu`` in :mod:`.bls`."""
 
@@ -924,8 +926,7 @@ class TpuBackend:
         if signature.point is None or not pubkeys:
             return False
         if _host_fast(1):
-            from .bls import _BACKENDS
-            return _BACKENDS["python"].verify(signature, pubkeys, message)
+            return _host_verify("verify", signature, pubkeys, message)
         return _dispatch(
             [(signature.point, [k.point for k in pubkeys], bytes(message))],
             rand_fn=lambda: 1)
@@ -935,9 +936,8 @@ class TpuBackend:
                 or len(pubkeys) != len(messages):
             return False
         if _host_fast(len(messages)):
-            from .bls import _BACKENDS
-            return _BACKENDS["python"].aggregate_verify(
-                signature, pubkeys, messages)
+            return _host_verify("aggregate_verify", signature, pubkeys,
+                                messages)
         # Distinct message per signer: one single-key set per message, the
         # aggregate signature attached to the first set, scalars all 1.
         entries = [(None, [pk.point], bytes(m))
@@ -950,8 +950,7 @@ class TpuBackend:
         if not sets:
             return False
         if _host_fast(len(sets)):
-            from .bls import _BACKENDS
-            return _BACKENDS["python"].verify_signature_sets(sets)
+            return _host_verify("verify_signature_sets", sets)
         entries = []
         for s in sets:
             if s.signature is None or s.signature.point is None:

@@ -25,10 +25,12 @@ layer that removes the serialization:
   outruns the uploads.
 
 Every stage boundary is instrumented through
-:mod:`~lighthouse_tpu.common.metrics` (``pipeline_host_prep_seconds``,
-``pipeline_h2d_seconds``, ``pipeline_h2d_wait_seconds``) and each
-executor keeps a ``stats`` dict the benchmarks surface as
-``stage_overlap_efficiency`` / ``push_overlap_ms``.
+:mod:`~lighthouse_tpu.common.metrics` (``<name>_host_prep_seconds``,
+``<name>_h2d_seconds``, ``pipeline_h2d_wait_seconds``) and each
+executor keeps a cumulative ``stats`` dict; under a JAX profiler session
+:meth:`StagedExecutor.map` also opens the ``<name>.prep`` /
+``<name>.stage`` / ``<name>.dispatch`` spans of
+:mod:`~lighthouse_tpu.common.tracing`.
 """
 
 from __future__ import annotations
@@ -40,6 +42,7 @@ from typing import Any, Callable, List, Optional, Sequence
 
 from ..common.device_ledger import LEDGER
 from ..common.metrics import observe
+from ..common.tracing import TRACER
 
 
 def _put_arrays(host):
@@ -114,6 +117,7 @@ class StagedExecutor:
         # the verify pipelines, "staging" for cold builds; None = the
         # caller accounts its own transfers).
         self.subsystem = subsystem
+        self._spans = (f"{name}.prep", f"{name}.stage", f"{name}.dispatch")
         self.stats = {
             "items": 0,
             "fallbacks": 0,
@@ -127,9 +131,11 @@ class StagedExecutor:
         t_wall = time.perf_counter()
         out: List[Any] = []
         in_flight = False  # a dispatch has been issued and not synced
+        sp_prep, sp_stage, sp_dispatch = self._spans
         for item in items:
             t0 = time.perf_counter()
-            host = prep(item)
+            with TRACER.span(sp_prep):
+                host = prep(item)
             dt = time.perf_counter() - t0
             observe(f"{self.name}_host_prep_seconds", dt)
             self.stats["host_prep_s"] += dt
@@ -141,15 +147,17 @@ class StagedExecutor:
                 LEDGER.note_transfer("h2d", _tree_nbytes(host),
                                      subsystem=self.subsystem)
             t0 = time.perf_counter()
-            try:
-                staged = self._stage(host)
-            except Exception:
-                self.stats["fallbacks"] += 1
-                staged = _sync_stage(host)
+            with TRACER.span(sp_stage):
+                try:
+                    staged = self._stage(host)
+                except Exception:
+                    self.stats["fallbacks"] += 1
+                    staged = _sync_stage(host)
             observe(f"{self.name}_h2d_seconds",
                     time.perf_counter() - t0)
             try:
-                out.append(dispatch(staged))
+                with TRACER.span(sp_dispatch):
+                    out.append(dispatch(staged))
             except Exception:
                 # An async device_put defers transfer errors to the
                 # point of consumption — they surface HERE, not in the

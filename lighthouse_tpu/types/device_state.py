@@ -37,6 +37,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..common.device_ledger import LEDGER
+from ..common.tracing import TRACER
 from ..ops.device_tree import DeviceTree, residency_snapshot
 from ..ops.merkle import _next_pow2
 from ..ops.tree_cache import fold_zero_cap
@@ -394,6 +395,20 @@ class DevicePackedCache:
             return self._root_inner(col)
 
     def _root_inner(self, col) -> bytes:
+        with TRACER.span("merkle.prep"):
+            plan = self._dirty_rows(col)
+        if isinstance(plan, bytes):
+            return plan
+        chunk_idx, rows, w, n = plan
+        with TRACER.span("merkle.scatter"):
+            root = self.tree.scatter(chunk_idx, rows)
+        return self._fold(root, w, n)
+
+    def _dirty_rows(self, col):
+        """Everything of a root before the scatter: the chunk diff and
+        the packed dirty rows, as ``(chunk_idx, rows, w, n)``; or the
+        root itself where no scatter is due (clean, adopted or
+        rebuilt)."""
         if isinstance(col, DeviceColumn):
             state, payload = col.consume()
         else:  # untracked plain column (a path the interception missed)
@@ -452,8 +467,7 @@ class DevicePackedCache:
                         if n else np.zeros(1, host.dtype),
                         np.zeros(1, host.dtype))
         rows = pack_chunk_rows(vals.reshape(chunk_idx.shape[0], per))
-        root = self.tree.scatter(chunk_idx, rows)
-        return self._fold(root, w, n)
+        return chunk_idx, rows, w, n
 
     def copy(self) -> "DevicePackedCache":
         out = DevicePackedCache.__new__(DevicePackedCache)

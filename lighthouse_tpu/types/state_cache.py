@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..common.tracing import TRACER
 from ..ops.merkle import merkleize_host
 from ..ops.tree_cache import (HASH_COUNT, IncrementalMerkleCache,
                               REBUILD_FRACTION)
@@ -293,6 +294,10 @@ class StateHashCache:
                 not ftype.is_fixed_size())
 
     def root(self, state) -> bytes:
+        with TRACER.span("state_root"):
+            return self._root(state)
+
+    def _root(self, state) -> bytes:
         from .device_state import (DeviceColumn, DevicePackedCache,
                                    wants_device_state, wrap_state_column)
         use_dev = wants_device_state(state)
@@ -306,46 +311,52 @@ class StateHashCache:
                               or (isinstance(v, np.ndarray)
                                   and v.ndim == 1)))
             if fname == "validators":
-                leaves.append(self.registry.root(v, ftype.LIMIT,
-                                                 device=use_dev))
+                with TRACER.span("state_root.registry", field=fname):
+                    leaves.append(self.registry.root(v, ftype.LIMIT,
+                                                     device=use_dev))
             elif is_packed and use_dev:
-                col = wrap_state_column(state, fname)
-                cache = self.device_packed.get(fname)
-                if cache is None:
-                    limit_chunks, mixin = self._packed_limits(ftype)
-                    cache = DevicePackedCache(limit_chunks, mixin)
-                    self.device_packed[fname] = cache
-                leaves.append(cache.root(col))
+                with TRACER.span("state_root.packed", field=fname):
+                    col = wrap_state_column(state, fname)
+                    cache = self.device_packed.get(fname)
+                    if cache is None:
+                        limit_chunks, mixin = self._packed_limits(ftype)
+                        cache = DevicePackedCache(limit_chunks, mixin)
+                        self.device_packed[fname] = cache
+                    leaves.append(cache.root(col))
             elif is_packed:
-                if isinstance(v, DeviceColumn):  # knob flipped off mid-life
-                    v = v.host()
-                cache = self.packed.get(fname)
-                if cache is None:
-                    _w, limit_chunks, length = ftype.leaf_words(v)
-                    cache = _PackedSourceCache(limit_chunks,
-                                               length is not None)
-                    self.packed[fname] = cache
-                leaves.append(cache.root(np.asarray(v)))
+                with TRACER.span("state_root.packed", field=fname):
+                    if isinstance(v, DeviceColumn):  # knob flipped off
+                        v = v.host()
+                    cache = self.packed.get(fname)
+                    if cache is None:
+                        _w, limit_chunks, length = ftype.leaf_words(v)
+                        cache = _PackedSourceCache(limit_chunks,
+                                                   length is not None)
+                        self.packed[fname] = cache
+                    leaves.append(cache.root(np.asarray(v)))
             elif hasattr(ftype, "leaf_words"):
-                words, limit_chunks, length = ftype.leaf_words(v)
-                cache = self.fields.get(fname)
-                if cache is None:
-                    cache = IncrementalMerkleCache(
-                        limit_chunks, mixin_length=length is not None)
-                    self.fields[fname] = cache
-                leaves.append(cache.root_words(words, length))
+                with TRACER.span("state_root.vectors", field=fname):
+                    words, limit_chunks, length = ftype.leaf_words(v)
+                    cache = self.fields.get(fname)
+                    if cache is None:
+                        cache = IncrementalMerkleCache(
+                            limit_chunks, mixin_length=length is not None)
+                        self.fields[fname] = cache
+                    leaves.append(cache.root_words(words, length))
             else:
-                enc = ftype.serialize(v)
-                memo = self.small.get(fname)
-                if memo is not None and memo[0] == enc:
-                    leaves.append(memo[1])
-                else:
-                    r = ftype.hash_tree_root(v)
-                    self.small[fname] = (enc, r)
-                    leaves.append(r)
+                with TRACER.span("state_root.small", field=fname):
+                    enc = ftype.serialize(v)
+                    memo = self.small.get(fname)
+                    if memo is not None and memo[0] == enc:
+                        leaves.append(memo[1])
+                    else:
+                        r = ftype.hash_tree_root(v)
+                        self.small[fname] = (enc, r)
+                        leaves.append(r)
         HASH_COUNT[0] += len(leaves)  # container fold, ~2 per leaf
         self.field_layer = leaves
-        return merkleize_host(leaves)
+        with TRACER.span("state_root.fold"):
+            return merkleize_host(leaves)
 
     def copy(self) -> "StateHashCache":
         out = StateHashCache.__new__(StateHashCache)

@@ -462,11 +462,11 @@ def test_stage_checker_flags_direct_reads():
         from lighthouse_tpu.crypto import tpu_backend as TB
 
         def row():
-            return dict(LAST_BLOCK_TIMINGS), dict(TB.LAST_PIPELINE_STATS)
+            return dict(LAST_BLOCK_TIMINGS), dict(TB.LAST_FAST_AGG_TIMINGS)
     """})
     d = details(found)
     assert "import:LAST_BLOCK_TIMINGS" in d
-    assert "attr:LAST_PIPELINE_STATS" in d
+    assert "attr:LAST_FAST_AGG_TIMINGS" in d
 
 
 def test_stage_checker_owner_module_and_adapter_pass():

@@ -82,7 +82,7 @@ def test_concurrent_thread_accounting_exact():
     assert snap["h2d_bytes"] - base["h2d_bytes"] == 3 * n_threads * per
     assert snap["h2d_ops"] - base["h2d_ops"] == n_threads * per
     assert snap["dispatches"] - base["dispatches"] == n_threads * per
-    assert snap["device_ms"] - base["device_ms"] == \
+    assert snap["dispatch_wall_ms"] - base["dispatch_wall_ms"] == \
         pytest.approx(0.5 * n_threads * per)
 
 
