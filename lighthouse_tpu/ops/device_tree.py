@@ -35,6 +35,7 @@ from collections.abc import Mapping
 import numpy as np
 
 from ..common.device_ledger import LEDGER
+from ..common.metrics import REGISTRY
 from .merkle import _next_pow2
 
 # Byte accounting for the residency story (surfaced by bench.py as
@@ -147,26 +148,94 @@ def pad_bucket(idx: np.ndarray, rows: np.ndarray) -> tuple:
     return pidx, prows
 
 
-def scatter_propagate_body(levels, idx, rows):
+def scatter_propagate_body(levels, idx, rows, *, use_kernel: bool):
     """The fused warm-root body: scatter ``rows`` into ``levels[0]`` at
     ``idx`` and re-hash exactly the touched ancestor path of every index
     up every level.  Duplicate indices (bucket padding) recompute the same
     parent with the same inputs — wasted lanes, never wrong bits.
 
+    ``use_kernel`` (static) picks the level compression: the unrolled
+    Pallas kernel (:func:`_hash64_kernel`) or the ``lax.scan`` ``hash64``
+    — bit-identical; :func:`scatter_uses_kernel` decides.
+
     Shared verbatim by the packed-column trees and the registry mirror
     (which feeds record-mini-tree roots as ``rows``), so one compiled
-    artifact per (bucket, width) covers both.
+    artifact per (bucket, width, route) covers both.
     """
     from .sha256 import hash64
 
+    h64 = _get_hash64_kernel_jit() if use_kernel else hash64
     out = [levels[0].at[idx].set(rows)]
     cur = idx
     for lvl in range(1, len(levels)):
         cur = cur >> 1
         below = out[-1]
-        h = hash64(below[2 * cur], below[2 * cur + 1])
+        h = h64(below[2 * cur], below[2 * cur + 1])
         out.append(levels[lvl].at[cur].set(h))
     return tuple(out)
+
+
+# The Pallas compression's lane tile: a level's lanes are padded up to it.
+_KERNEL_MIN_LANES = 128
+# Its widest block (the VMEM bound shared with ``_levels_body``).
+_KERNEL_MAX_BLOCK_LOG2 = 15
+
+
+def _hash64_kernel(left, right):
+    """``hash64`` of one level's ``(n, 8)`` child pairs (n a power of two,
+    the bucket) through :func:`..ops.merkle_kernel.hash64_pallas`, in
+    blocks of ``min(n, 2^15)`` lanes.  A bucket under the 128-lane tile is
+    padded with copies of lane 0 and the pad lanes are dropped before the
+    level's scatter — the same idempotent padding as :func:`pad_bucket`."""
+    import jax.numpy as jnp
+
+    from .merkle_kernel import hash64_pallas
+
+    n = left.shape[0]
+    if n < _KERNEL_MIN_LANES:
+        def pad(x):
+            return jnp.concatenate(
+                [x, jnp.broadcast_to(x[:1], (_KERNEL_MIN_LANES - n, 8))])
+        return _hash64_kernel(pad(left), pad(right))[:n]
+    return hash64_pallas(left, right, block_log2=min(
+        n.bit_length() - 1, _KERNEL_MAX_BLOCK_LOG2))
+
+
+_hash64_kernel_jit = None
+
+
+def _get_hash64_kernel_jit():
+    """Every level of one bucket hashes the same shape: as a nested jit
+    the unrolled kernel is traced and lowered once per shape, not once
+    per level (inlined into the enclosing program — no dispatch, no
+    program of its own)."""
+    global _hash64_kernel_jit
+    import jax
+    if _hash64_kernel_jit is None:
+        _hash64_kernel_jit = jax.jit(_hash64_kernel)
+    return _hash64_kernel_jit
+
+
+def scatter_uses_kernel() -> bool:
+    """The warm scatter's compression route, from what the process can
+    observe — the rule of the rebuild's ``_levels_body``: the Pallas
+    kernel where Mosaic lowers it (TPU) and the tree's levels sit on one
+    device; the scan body on the CPU and on a sharded mesh."""
+    from ..parallel.mesh import axis_size
+    return _use_kernel() and axis_size() == 1
+
+
+_SCATTER_LEVELS = REGISTRY.counter(
+    "device_tree_scatter_levels_total",
+    "tree levels re-hashed by warm scatters, by compression route",
+    labelnames=("route",))
+
+
+def note_scatter_levels(levels, use_kernel: bool) -> None:
+    """Count one warm scatter's re-hashed levels (host side, from the
+    static tree depth) under the route it compiled with."""
+    _SCATTER_LEVELS.labels(
+        route="kernel" if use_kernel else "scan").inc(len(levels) - 1)
 
 
 _scatter_jit = None
@@ -178,11 +247,13 @@ def _get_scatter_jit(donate: bool):
     import jax
     if donate:
         if _scatter_jit_donated is None:
-            _scatter_jit_donated = jax.jit(scatter_propagate_body,
-                                           donate_argnums=(0,))
+            _scatter_jit_donated = jax.jit(
+                scatter_propagate_body, donate_argnums=(0,),
+                static_argnames=("use_kernel",))
         return _scatter_jit_donated
     if _scatter_jit is None:
-        _scatter_jit = jax.jit(scatter_propagate_body)
+        _scatter_jit = jax.jit(scatter_propagate_body,
+                               static_argnames=("use_kernel",))
     return _scatter_jit
 
 
@@ -309,21 +380,22 @@ class DeviceTree:
         from ..parallel.mesh import mesh_put
         pidx, prows = pad_bucket(np.asarray(idx),
                                  np.ascontiguousarray(rows, dtype=np.uint32))
-        LEDGER.note_event("scatters")
-        jit = _get_scatter_jit(_donation_works() and not self.shared)
-        self.levels = jit(self.levels, mesh_put("tree_dirty", pidx),
-                          mesh_put("tree_dirty", prows))
-        self.shared = False  # the update produced buffers only we hold
-        self.note_residency()
-        return self.root_words()
+        return self._propagate(mesh_put("tree_dirty", pidx),
+                               mesh_put("tree_dirty", prows))
 
     def scatter_device(self, idx_dev, rows_dev) -> np.ndarray:
         """Scatter with (idx, rows) already device-resident (registry
         mirror path) — zero push here; the caller accounted its own."""
+        return self._propagate(idx_dev, rows_dev)
+
+    def _propagate(self, idx_dev, rows_dev) -> np.ndarray:
         LEDGER.note_event("scatters")
+        use_kernel = scatter_uses_kernel()
+        note_scatter_levels(self.levels, use_kernel)
         jit = _get_scatter_jit(_donation_works() and not self.shared)
-        self.levels = jit(self.levels, idx_dev, rows_dev)
-        self.shared = False
+        self.levels = jit(self.levels, idx_dev, rows_dev,
+                          use_kernel=use_kernel)
+        self.shared = False  # the update produced buffers only we hold
         self.note_residency()
         return self.root_words()
 

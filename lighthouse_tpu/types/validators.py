@@ -775,15 +775,18 @@ def _pad_rows_bucket(idx: np.ndarray, rows: dict) -> tuple:
     return pidx, out
 
 
-def _mirror_scatter_body(levels, cols, idx, rows):
+def _mirror_scatter_body(levels, cols, idx, rows, *, use_kernel: bool):
     """The fused warm-root program: scatter the raw rows into the HBM
     columns, re-hash exactly those records' 8-leaf mini-trees, and
     propagate their ancestor paths through the record-root tree — leaf
-    re-hash → level propagation as ONE jitted dispatch."""
+    re-hash → level propagation as ONE jitted dispatch.  ``use_kernel``
+    (static) is the path re-hash's route
+    (:func:`..ops.device_tree.scatter_uses_kernel`)."""
     from ..ops.device_tree import scatter_propagate_body
     new_cols = {k: cols[k].at[idx].set(rows[k]) for k in cols}
     rec = _record_roots_body(rows, use_kernel=False)  # k records: XLA h64
-    return new_cols, scatter_propagate_body(levels, idx, rec)
+    return new_cols, scatter_propagate_body(levels, idx, rec,
+                                            use_kernel=use_kernel)
 
 
 def _mirror_rebuild_body(cols, n_arr, *, use_kernel: bool):
@@ -814,8 +817,9 @@ def _get_mirror_scatter_jit(donate: bool):
     import jax
     jit = _mirror_scatter_jits.get(donate)
     if jit is None:
-        jit = (jax.jit(_mirror_scatter_body, donate_argnums=(0, 1))
-               if donate else jax.jit(_mirror_scatter_body))
+        jit = jax.jit(_mirror_scatter_body,
+                      donate_argnums=(0, 1) if donate else (),
+                      static_argnames=("use_kernel",))
         _mirror_scatter_jits[donate] = jit
     return jit
 
@@ -970,7 +974,8 @@ class DeviceRegistryMirror:
         the new subtree root words.  H2D = the bucket-padded raw rows
         (the replicated ``registry_dirty`` mesh family)."""
         from ..common.device_ledger import LEDGER
-        from ..ops.device_tree import _donation_works
+        from ..ops.device_tree import (_donation_works, note_scatter_levels,
+                                       scatter_uses_kernel)
         from ..ops.tree_cache import HASH_COUNT
         from ..parallel.mesh import mesh_put
 
@@ -979,6 +984,8 @@ class DeviceRegistryMirror:
                                           _registry_raw_rows(reg, idx))
             LEDGER.note_event("scatters")
             HASH_COUNT[0] += pidx.shape[0] * (8 + len(self.tree.levels) - 1)
+            use_kernel = scatter_uses_kernel()
+            note_scatter_levels(self.tree.levels, use_kernel)
             jit = _get_mirror_scatter_jit(
                 _donation_works() and not self.shared
                 and not self.tree.shared)
@@ -986,7 +993,7 @@ class DeviceRegistryMirror:
                 self.tree.levels, self.cols,
                 mesh_put("registry_dirty", pidx),
                 {k: mesh_put("registry_dirty", v)
-                 for k, v in rows.items()})
+                 for k, v in rows.items()}, use_kernel=use_kernel)
             self.shared = False
             self.tree.shared = False
             self.note_residency()

@@ -8,7 +8,8 @@ The programs are compiled with the MXU band products on (the chip's
 default) and the persistent compilation cache off: a described-device
 executable is written to the cache but cannot be read back without a
 chip.  The quick tier holds the kernels that compile in seconds plus the
-Merkle kernel at the 2^20-leaf state width; the BLS kernels that take
+Merkle kernel at the 2^20-leaf state width and the warm root's scatter at
+the state cell's tree shapes; the BLS kernels that take
 tens of seconds to minutes (hash-to-curve, σ fold, Miller cell,
 finalize) and the 4-chip sharded verify on the described 2x2 are
 ``slow``.
@@ -73,6 +74,27 @@ def test_merkle_kernel_state_width(chip_compile):
     text = chip_compile(MK.chunk_roots_natural, [((1 << 20, 8), U32)],
                         chunk_log2=MK.CHUNK_LOG2, use_kernel=True)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("width_log2,bucket", [
+    (15, 1 << 15), (18, 1 << 10), (18, 8)])
+def test_warm_scatter_kernel_route(chip_compile, one_chip, width_log2,
+                                   bucket):
+    """The warm root's donated scatter program on the Pallas route at the
+    state cell's two trees (participation 2^15 leaves with a full bucket,
+    balances 2^18 with 1,024 dirty chunks) and a bucket padded to the
+    kernel's 128-lane tile: one kernel per level."""
+    from lighthouse_tpu.ops import device_tree as DT
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    levels = tuple(arg((1 << (width_log2 - i), 8), U32)
+                   for i in range(width_log2 + 1))
+    text = DT._get_scatter_jit(True).lower(
+        levels, arg((bucket,), I32), arg((bucket, 8), U32),
+        use_kernel=True).compile().as_text()
+    assert text.count("tpu_custom_call") == width_log2
 
 
 @pytest.mark.parametrize("K", [1, 16])
