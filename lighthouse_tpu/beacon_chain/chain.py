@@ -467,8 +467,14 @@ class BeaconChain:
             return self.slot_clock.now()
         return self.fork_choice.current_slot
 
-    def per_slot_task(self, slot: int) -> None:
-        """`timer` service hook (`beacon_chain.rs:5322`)."""
+    def per_slot_task(self, slot: int, *, advance_head: bool = True) -> None:
+        """`timer` service hook (`beacon_chain.rs:5322`).
+
+        ``advance_head=False`` keeps the bookkeeping and skips the
+        state-advance timer: a chain segment's import ticks each block's
+        slot, and pre-advancing every imported block's post-state for
+        duties at a slot the wall clock left long ago is wasted work
+        (Lighthouse's timer skips a head this far behind the clock)."""
         TRACER.set_slot(slot)  # ambient slot scope for this tick's spans
         # Ledger slot boundary: close the previous slot's device-transfer
         # delta (the /lighthouse/device per-slot view, keyed like the
@@ -496,7 +502,7 @@ class BeaconChain:
         # the hot path.  Epoch boundaries are exactly where the advance is
         # expensive AND where the shuffling changes, so warming it here
         # moves that cost off the gossip deadline.
-        if slot > self.head.slot:
+        if advance_head and slot > self.head.slot:
             self._advance_and_prime(slot)
 
     def _advance_and_prime(self, target_slot: int) -> None:
@@ -802,7 +808,15 @@ class BeaconChain:
             _sp.set(root=ex.block_root.hex())
             return ex.block_root
 
-    def _import_block(self, ex: ExecutedBlock, *, is_timely: bool) -> None:
+    def _import_block(self, ex: ExecutedBlock, *, is_timely: bool,
+                      light_client: bool = True,
+                      recompute_head: bool = True) -> None:
+        """Commit one executed block.  A chain segment commits its blocks
+        with ``recompute_head=False`` and recomputes the head itself, as
+        Lighthouse's ``process_chain_segment`` does once per segment, and
+        with ``light_client=False`` but for the last block: every earlier
+        block's head and light-client updates are superseded before anyone
+        could read them."""
         block_root = ex.block_root
         state = ex.post_state
         state_root = bytes(ex.signed_block.message.state_root)
@@ -814,8 +828,7 @@ class BeaconChain:
             # the whole import or none of it — never a block without its
             # state or a state without its journal record.
             ops = self.store.block_put_ops(block_root, ex.signed_block)
-            ops += self.store.state_put_ops(state_root, state.copy(),
-                                            block_root)
+            ops += self.store.state_put_ops(state_root, state, block_root)
             for sc in self.data_availability.take_sidecars(block_root):
                 ops += self.store.blob_put_ops(block_root, int(sc.index),
                                                sc)
@@ -848,8 +861,10 @@ class BeaconChain:
         self.event_bus.publish("block", {
             "slot": str(int(ex.signed_block.message.slot)),
             "block": "0x" + block_root.hex()})
-        self._produce_light_client_updates(ex.signed_block)
-        self.recompute_head()
+        if light_client:
+            self._produce_light_client_updates(ex.signed_block)
+        if recompute_head:
+            self.recompute_head()
         # Bound the snapshot cache (weak #10: between finalizations this
         # otherwise held EVERY post-state — up to 2 epochs × ~100 MB at
         # registry scale).  Evicted states remain loadable from the store.

@@ -1179,13 +1179,22 @@ def block_sig_dispatch(device_fn, sets) -> tuple:
     envelope — and therefore its circuit breaker — with every other
     non-streamed verify, so a device outage degrades block batches to
     the host oracle through the SAME machinery (zero new failure modes)
-    and bench's breaker attribution sees the block path too.  Returns
+    and bench's breaker attribution sees the block path too.
+
+    The envelope's deadline bounds ONE device dispatch; a chain
+    segment's chunk of sets makes several (the backend's
+    :func:`~..crypto.tpu_backend.dispatch_count`), so the batch gets the
+    deadline once per dispatch it makes: a sound batch stays on the
+    device, and a wedged device is still abandoned.  Returns
     ``(verdict, path)``."""
-    from ..crypto import bls
+    from ..crypto import bls, tpu_backend
     env = global_bls_envelope()
+    deadline_s = env.deadline_s
+    if deadline_s is not None:
+        deadline_s *= tpu_backend.dispatch_count(sets)
     ok, path = env.call(device_fn,
                         bls._BACKENDS["python"].verify_signature_sets,
-                        (sets,))
+                        (sets,), deadline_s=deadline_s)
     return bool(ok), path
 
 

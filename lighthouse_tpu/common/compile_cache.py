@@ -202,17 +202,20 @@ def warmup(buckets: Sequence[Tuple[int, int]] = DEFAULT_BUCKETS,
     fq12 = aval((PK.FQ12_ROWS, S), u32)
     g2pt = aval((3, 2, PK.LIMBS), u32)
     part = aval((6 * PK.BLOCK_ROWS, S), u32)
+    hash_g2, sigma, finalize = TB.kernel_programs()
     for fn, args in (
-            (HK.hash_g2_kernel_call, (aval((2 * HK.BLOCK_ROWS, 2 * S), u32),)),
-            (PK.sigma_kernel_call, (aval((128, S), u32), aval((1, S), i32),
-                                    aval((1, S), u32), aval((1, S), u32))),
+            (hash_g2, (aval((2 * HK.BLOCK_ROWS, 2 * S), u32),)),
+            (sigma, (aval((128, S), u32), aval((1, S), i32),
+                     aval((1, S), u32), aval((1, S), u32))),
             (TB._sigma_point, (part,)),
             (TB._sigma_add, (g2pt, part)),
             (TB._sigma_block, (g2pt, aval((), np.bool_))),
             (TB._shared_lanes, (g2pt, g2pt)),
-            (TB._cell_bad, (aval((1, S), i32), aval((1, S), i32))),
+            (TB._cell_bad, (aval((1, S), i32), aval((1, S), i32),
+                            aval((), np.bool_))),
+            (TB._combine_verdict, (aval((1, 1), i32), aval((), np.bool_))),
             (TB._miller_cell, blk + blk),
             (TB._fold_pair, (fq12, fq12)),
-            (PK.finalize_kernel_call_donated, (fq12,))):
+            (finalize, (fq12,))):
         fn.lower(*args).compile()
     return {"cache_dir": cache_dir(), "compiled": compiled}

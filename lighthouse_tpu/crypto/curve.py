@@ -286,7 +286,9 @@ def g2_compress(p) -> bytes:
     return bytes(out)
 
 
-def g2_decompress(b: bytes):
+def g2_x_of(b: bytes):
+    """The x-coordinate and the "y is larger" flag of a compressed G2
+    encoding, or None for the encoding of infinity."""
     if len(b) != 96:
         raise ValueError("G2 compressed point must be 96 bytes")
     flags = b[0]
@@ -298,10 +300,22 @@ def g2_decompress(b: bytes):
         return None
     x1 = _fq_from_bytes(bytes([flags & 0x1F]) + b[1:48])
     x0 = _fq_from_bytes(b[48:])
-    x = (x0, x1)
+    return (x0, x1), bool(flags & 0x20)
+
+
+def g2_with_sign(x, y, larger: bool):
+    """``(x, ±y)``: the root whose "is larger" test matches ``larger``."""
+    if larger != _y_is_larger_fq2(y):
+        y = F.fq2_neg(y)
+    return (x, y)
+
+
+def g2_decompress(b: bytes):
+    xs = g2_x_of(b)
+    if xs is None:
+        return None
+    x, larger = xs
     y = F.fq2_sqrt(F.fq2_add(F.fq2_mul(F.fq2_sqr(x), x), FQ2.b))
     if y is None:
         raise ValueError("x not on curve")
-    if bool(flags & 0x20) != _y_is_larger_fq2(y):
-        y = F.fq2_neg(y)
-    return (x, y)
+    return g2_with_sign(x, y, larger)

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import os
 from functools import lru_cache, partial
+from itertools import repeat
 from typing import Sequence
 
 import numpy as np
@@ -40,6 +41,7 @@ from . import curve as C
 from . import limb_curve as LC
 from . import limb_field as LF
 from . import limb_pairing as LP
+from ..common.program_cache import program
 from ..ops.merkle import _next_pow2
 from .hash_to_curve import hash_to_g2
 
@@ -138,6 +140,17 @@ def _g2_aff_col(point) -> bytes:
     return col.tobytes()
 
 
+def _mont_rows(values) -> np.ndarray:
+    """Ints → ``(26, n)`` Montgomery-domain 16-bit limbs (the layout
+    :func:`LF.to_mont` gives one int), converted in bulk."""
+    n_int, r = LF.N_INT, LF.R_MOD_N
+    nbytes = 2 * LF.LIMBS
+    raw = b"".join((v % n_int * r % n_int).to_bytes(nbytes, "little")
+                   for v in values)
+    return np.frombuffer(raw, "<u2").reshape(-1, LF.LIMBS).T.astype(
+        np.uint32)
+
+
 class _DevicePubkeyTable:
     """HBM-resident decompressed pubkey columns — the device half of the
     reference's ``ValidatorPubkeyCache`` (``validator_pubkey_cache.rs:18``):
@@ -218,6 +231,44 @@ class _DevicePubkeyTable:
             self._n += 1
         return i
 
+    def add_all(self, points) -> None:
+        """Every one of ``points`` not yet in the table, appended in order
+        with its columns converted in bulk — a node's boot-time fill of
+        its whole registry (``index_of`` builds one column at a time).
+        The device copy is re-uploaded whole on the next ``device()``."""
+        new = [p for p in dict.fromkeys(points) if p not in self._index]
+        if not new:
+            return
+        cap = self._host.shape[1]
+        while cap < self._n + len(new):
+            cap *= 2
+        if cap != self._host.shape[1]:
+            host = np.zeros((64, cap), np.uint32)
+            host[:, :self._n] = self._host[:, :self._n]
+            self._host = host
+        lo, hi = self._n, self._n + len(new)
+        self._host[0:26, lo:hi] = _mont_rows(p[0] for p in new)
+        self._host[32:58, lo:hi] = _mont_rows(p[1] for p in new)
+        self._index.update(zip(new, range(lo, hi)))
+        self._last_used.update(zip(new, repeat(self._gen)))
+        self._n = hi
+        self._device = None
+
+    def indices_of(self, points: list) -> np.ndarray:
+        """:meth:`index_of` of each of ``points``, in order, as int32.
+        Resident keys (the steady state: a chain segment at 2^20
+        validators looks up ~4M keys a batch) take one C-level recency
+        update and one dict sweep, with no interpreter frame per key;
+        only the keys not yet in the table go through
+        :meth:`index_of`."""
+        self._last_used.update(zip(points, repeat(self._gen)))
+        out = list(map(self._index.get, points))
+        if None in out:
+            for j, i in enumerate(out):
+                if i is None:
+                    out[j] = self.index_of(points[j])
+        return np.array(out, np.int32)
+
     def device(self):
         if self._device is None:
             self._device = jnp.asarray(self._host)
@@ -250,6 +301,7 @@ _SIGMA_G1_CELL = _sigma_g1_cell()
 # only issues work and the device queue stays full.
 
 
+@program
 @partial(jax.jit, static_argnames=("K",))
 def _prepare_cell(table, idx, kmask, lo, hi, *, K: int):
     """Pubkey gather + G1 aggregation + RLC ladder + affine for one
@@ -259,23 +311,29 @@ def _prepare_cell(table, idx, kmask, lo, hi, *, K: int):
     return PK.prepare_kernel_call(pk, kmask, lo, hi, K=K)
 
 
+@program
 @jax.jit
-def _cell_bad(flags, setlive):
-    return jnp.any((flags != 0) & (setlive != 0))
+def _cell_bad(flags, setlive, bad):
+    """``bad``, or a live set of this chunk with an identity aggregate
+    key: one fixed-shape flag carried through a dispatch's chunks."""
+    return bad | jnp.any((flags != 0) & (setlive != 0))
 
 
+@program
 @jax.jit
 def _sigma_point(partial):
     from . import pairing_kernel as PK
     return PK.sigma_point(partial)
 
 
+@program
 @jax.jit
 def _sigma_add(acc, partial):
     from . import pairing_kernel as PK
     return PK.sigma_add(acc, partial)
 
 
+@program
 @jax.jit
 def _sigma_block(acc, live):
     """Σ c_i·σ_i → the Miller block that pairs it with −G: (128, 128) G2
@@ -288,6 +346,7 @@ def _sigma_block(acc, live):
     return g2, mask
 
 
+@program
 @jax.jit
 def _miller_cell(g1a, g2a, ma, g1b, g2b, mb):
     """Two 128-lane blocks → one fused Miller + lane-fold cell →
@@ -299,6 +358,7 @@ def _miller_cell(g1a, g2a, ma, g1b, g2b, mb):
         jnp.concatenate([ma, mb], axis=1))
 
 
+@program
 @jax.jit
 def _fold_pair(a, b):
     from . import pairing_kernel as PK
@@ -323,9 +383,9 @@ def _miller_blocks(g1s, g2s, masks) -> list:
     """Pair 128-lane blocks into Miller cells (an odd count padded with
     a masked zero block) → their (384, 128) folded products."""
     if len(g1s) % 2:
-        g1s.append(jnp.zeros((64, 128), jnp.uint32))
-        g2s.append(jnp.zeros((128, 128), jnp.uint32))
-        masks.append(jnp.zeros((1, 128), jnp.int32))
+        g1s.append(np.zeros((64, 128), np.uint32))
+        g2s.append(np.zeros((128, 128), np.uint32))
+        masks.append(np.zeros((1, 128), np.int32))
     return [_miller_cell(g1s[i], g2s[i], masks[i],
                          g1s[i + 1], g2s[i + 1], masks[i + 1])
             for i in range(0, len(g1s), 2)]
@@ -337,40 +397,61 @@ def _finalize(prod):
     (CPU donation is a warning-only no-op)."""
     from . import pairing_kernel as PK
     if _use_pallas():
-        return PK.finalize_kernel_call_donated(prod)
+        return kernel_programs()[2](prod)
     return PK.finalize_kernel_call(prod)
 
 
-def _pipeline_cells(table, staged):
+_PROGRAMS: dict = {}
+
+
+def _program_of(jitted, donate_argnums=()):
+    """``jitted`` as a :func:`program`, one per function object."""
+    prog = _PROGRAMS.get(jitted)
+    if prog is None:
+        prog = _PROGRAMS[jitted] = program(jitted,
+                                           donate_argnums=donate_argnums)
+    return prog
+
+
+def kernel_programs():
+    """The kernel modules' host-called programs as :func:`program`s:
+    hash-to-curve, the σ-side fold and the donated finalize."""
+    from . import htc_kernel as HK
+    from . import pairing_kernel as PK
+    return (_program_of(HK.hash_g2_kernel_call),
+            _program_of(PK.sigma_kernel_call),
+            _program_of(PK.finalize_kernel_call_donated, donate_argnums=(0,)))
+
+
+def _pipeline_cells(table, staged, bad):
     """Batch verify of one marshalled sub-batch up to its (384, 128)
     residue product: per chunk — prepare, hash-to-curve, σ-side RLC
     fold — then the Miller cells over the chunk blocks plus the ONE σ
-    lane (e(−G, Σ c_i·σ_i)), folded pairwise.  Returns (product, [bad
-    flags])."""
-    from . import htc_kernel as HK
-    from . import pairing_kernel as PK
-
+    lane (e(−G, Σ c_i·σ_i)), folded pairwise.  Returns (product, the
+    ``bad`` flag with this sub-batch's identity-key flags ORed in)."""
+    hash_g2, sigma, _finalize_donated = kernel_programs()
     cells, K, any_sig = staged
-    g1s, g2s, masks, bads = [], [], [], []
+    g1s, g2s, masks = [], [], []
     acc = None
     for (idx, kmask, lo, hi, u, sig, sigmask, setlive) in cells:
         g1, flags = _prepare_cell(table, idx, kmask, lo, hi, K=K)
         g1s.append(g1)
-        g2s.append(HK.hash_g2_kernel_call(u))
+        g2s.append(hash_g2(u))
         masks.append(setlive)
-        part = PK.sigma_kernel_call(sig, sigmask, lo, hi)
+        part = sigma(sig, sigmask, lo, hi)
         acc = _sigma_point(part) if acc is None else _sigma_add(acc, part)
-        bads.append(_cell_bad(flags, setlive))
+        bad = _cell_bad(flags, setlive, bad)
     g2_sig, sig_mask = _sigma_block(acc, jnp.bool_(any_sig))
     g1s.append(jnp.asarray(_SIGMA_G1_CELL))
     g2s.append(g2_sig)
     masks.append(sig_mask)
-    return _fold_blocks(_miller_blocks(g1s, g2s, masks)), bads
+    return _fold_blocks(_miller_blocks(g1s, g2s, masks)), bad
 
 
+@program
 @jax.jit
-def _combine_verdict(ok, bads):
-    return (ok[0, 0] != 0) & ~jnp.any(bads)
+def _combine_verdict(ok, bad):
+    return (ok[0, 0] != 0) & ~bad
 
 
 def _fq12_one_block() -> np.ndarray:
@@ -436,9 +517,9 @@ def _cells(C: int, *planes) -> list:
 def _marshal_planes(entries, rand_fn, C: int):
     """Host marshalling of ``entries`` into ``C`` chunk-major 128-set
     chunks: pubkey-table indices, RLC scalar words, u-values, signature
-    columns, masks.  Column placement is vectorized — the only per-entry
-    Python work left is the pubkey-table dict lookups, the memoised
-    u-column lookups, and ``rand_fn``.  Returns (idx, kmask, lo, hi,
+    columns, masks.  Column placement is vectorized, and the pubkey-table
+    lookups are one dict sweep — the only per-entry Python work left is
+    the memoised u-column lookups and ``rand_fn``.  Returns (idx, kmask, lo, hi,
     u_planes, sig_cols, sigmask, setlive, set_col, K)."""
     from . import pairing_kernel as PK
 
@@ -448,9 +529,7 @@ def _marshal_planes(entries, rand_fn, C: int):
 
     nkeys = np.fromiter((len(e[1]) for e in entries), np.int64, n)
     total_keys = int(nkeys.sum())
-    flat_idx = np.fromiter(
-        (_PK_TABLE.index_of(kp) for e in entries for kp in e[1]),
-        np.int32, total_keys)
+    flat_idx = _PK_TABLE.indices_of([kp for e in entries for kp in e[1]])
     sets = np.arange(n)
     c_arr, s_arr = sets // S, sets % S
     starts = np.concatenate([[0], np.cumsum(nkeys)[:-1]])
@@ -524,6 +603,14 @@ def _split_batches(entries) -> list:
     return work
 
 
+def dispatch_count(sets) -> int:
+    """Device dispatches one :meth:`TpuBackend.verify_signature_sets`
+    call makes for ``sets``: the work items of :func:`_split_batches`
+    (one per K bucket, or per pipeline sub-batch of one)."""
+    return max(1, len(_split_batches(
+        [(s.signature, s.signing_keys, None) for s in sets])))
+
+
 def _dispatch_pallas(entries, rand_fn) -> bool:
     """Marshal a batch and run the fused device pipeline:
 
@@ -553,19 +640,24 @@ def _dispatch_pallas(entries, rand_fn) -> bool:
     work = _split_batches(entries)
     ex = StagedExecutor("bls_pipeline", subsystem="bls")
 
+    # The identity-key flag, carried through every chunk in dispatch
+    # order (one fixed shape however many chunks the call makes).
+    bad = np.False_
+
     def dispatch(staged):
         # Table snapshot AFTER this sub-batch's marshalling registered
         # its new keys; later sub-batches' appends build NEW functional
         # arrays and cannot disturb an in-flight dispatch.
-        return _pipeline_cells(_PK_TABLE.device(), staged)
+        nonlocal bad
+        prod, bad = _pipeline_cells(_PK_TABLE.device(), staged, bad)
+        return prod
 
     results = ex.map(work, lambda batch: _marshal_group(batch, rand_fn),
                      dispatch)
-    prod = _fold_blocks([r[0] for r in results])
-    bads = [b for r in results for b in r[1]]
+    prod = _fold_blocks(list(results))
     ok = _finalize(prod)
     with TRACER.span("bls.verdict_sync"):
-        verdict = bool(_combine_verdict(ok, jnp.stack(bads)))
+        verdict = bool(_combine_verdict(ok, bad))
     # Self-accounted like the XLA direct path (suppressed, and counted
     # once by the envelope, when a resilience envelope wraps the call).
     LEDGER.note_dispatch("bls", (time.perf_counter() - t0) * 1e3)
@@ -597,9 +689,17 @@ def _dedup_shared_keygroups(entries):
     import time
 
     from . import bls
-    counts: dict = {}
+    # Candidates first by (count, first key, last key): only lists that
+    # share those are hashed whole (a chain segment's ~8,400 sets of ~486
+    # distinct-list keys would otherwise all be).
+    heads: dict = {}
     for e in entries:
         if len(e[1]) > 4:
+            h = (len(e[1]), e[1][0], e[1][-1])
+            heads[h] = heads.get(h, 0) + 1
+    counts: dict = {}
+    for e in entries:
+        if len(e[1]) > 4 and heads[(len(e[1]), e[1][0], e[1][-1])] >= 2:
             counts[tuple(e[1])] = counts.get(tuple(e[1]), 0) + 1
     shared = {k for k, n in counts.items() if n >= 2}
     if not shared:
@@ -614,7 +714,8 @@ def _dedup_shared_keygroups(entries):
         (time.perf_counter() - t0) * 1e3, 2)
     out = []
     for e in entries:
-        key = tuple(e[1])
+        key = (tuple(e[1]) if len(e[1]) > 4
+               and heads[(len(e[1]), e[1][0], e[1][-1])] >= 2 else None)
         if key in shared:
             out.append((e[0], [agg[key]], e[2]))
         else:
@@ -728,10 +829,10 @@ def _dispatch_shared_pallas(entries, pk_pt, rand_fn) -> bool:
     with 2 live lanes → shared finalize."""
     import time
 
-    from . import htc_kernel as HK
     from . import pairing_kernel as PK
     from ..common.device_ledger import LEDGER
 
+    hash_g2, sigma, _ = kernel_programs()
     S = PK.PREP_S
     n = len(entries)
     # NOT named C: that would shadow the curve module used below.
@@ -748,12 +849,12 @@ def _dispatch_shared_pallas(entries, pk_pt, rand_fn) -> bool:
     pk_col[:, 0] = np.frombuffer(_g1_aff_col(pk_pt), np.uint32)
     pk_col[:, 1] = np.frombuffer(_g1_aff_col(C.g1_neg(C.G1_GEN)), np.uint32)
 
-    h_cols = [HK.hash_g2_kernel_call(c[2]) for c in cells]
+    h_cols = [hash_g2(c[2]) for c in cells]
     t0 = _stage_sync(timings, "htc_ms", t0, h_cols)
     h_acc = s_acc = None
     for (lo_c, hi_c, _u, sig_c, sm_c, live_c), h_c in zip(cells, h_cols):
-        hp = PK.sigma_kernel_call(h_c, live_c, lo_c, hi_c)
-        sp = PK.sigma_kernel_call(sig_c, sm_c, lo_c, hi_c)
+        hp = sigma(h_c, live_c, lo_c, hi_c)
+        sp = sigma(sig_c, sm_c, lo_c, hi_c)
         h_acc = _sigma_point(hp) if h_acc is None else _sigma_add(h_acc, hp)
         s_acc = _sigma_point(sp) if s_acc is None else _sigma_add(s_acc, sp)
     t0 = _stage_sync(timings, "rlc_fold_ms", t0, h_acc, s_acc)
@@ -771,6 +872,7 @@ def _dispatch_shared_pallas(entries, pk_pt, rand_fn) -> bool:
     return verdict
 
 
+@program
 @jax.jit
 def _shared_lanes(h_acc, s_acc):
     """The collapsed path's two G2 lanes — Σ c_i·H(m_i) in lane 0 (paired

@@ -11,11 +11,13 @@ idle.  :class:`EpochReplayer` fuses three things across the window
 1. **Signatures** — every block runs under
    ``SignatureStrategy.BATCH_DEFERRED``: the per-block
    :class:`~.per_block.SigAccumulator` collects its sets without
-   verifying, the window owner concatenates them and dispatches ONE
-   batch through :mod:`.sig_dispatch` (mesh-sharded ``parallel/bls_shard``
-   on a TPU backend).  The verdict gates commit of the WHOLE window; on
-   ``False`` the per-block set slices are re-verified serially to name
-   the exact offending block (:class:`WindowSignaturesInvalid`).
+   verifying, and the window owner dispatches them through
+   :mod:`.sig_dispatch` (mesh-sharded ``parallel/bls_shard`` on a TPU
+   backend) in chunks of two pipeline sub-batches as the blocks apply,
+   so the device verifies under the host's transition work.  The
+   verdicts together gate commit of the WHOLE window; on ``False`` the
+   per-block set slices are re-verified serially to name the exact
+   offending block (:class:`WindowSignaturesInvalid`).
 2. **State roots** — per-slot ``tree_hash_root`` collapses to known
    roots: the caller's ``state_root_fn`` (store-fed) where present, else
    the blocks' own claimed ``state_root``s; ONE root is computed at the
@@ -42,7 +44,9 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..common.knobs import knob_tribool
+from ..common.tracing import TRACER
 from ..crypto import bls as B
+from ..ops.merkle import _next_pow2
 from .block_replayer import BlockReplayer
 from .per_block import (
     BlockProcessingError,
@@ -120,8 +124,31 @@ def _set_bytes(sets: Sequence[B.SignatureSet]) -> int:
     return sum(32 + 96 + 48 * len(s.signing_keys) for s in sets)
 
 
+def _dispatch_chunk() -> int:
+    """Sets per block-signature dispatch of a window: two of the TPU
+    pipeline's sub-batches (``LIGHTHOUSE_TPU_PIPELINE_SETS``; 0 sends
+    the whole window as one)."""
+    from ..common.knobs import knob_int
+    sub = knob_int("LIGHTHOUSE_TPU_PIPELINE_SETS")
+    return 2 * sub if sub > 0 else 1 << 62
+
+
+def _window_signatures(blocks: Sequence) -> List[bytes]:
+    """Every signature encoding the window's set builders deserialize,
+    in the order they meet them."""
+    out: List[bytes] = []
+    for signed in blocks:
+        body = signed.message.body
+        out.append(bytes(signed.signature))
+        out.append(bytes(body.randao_reveal))
+        out.extend(bytes(a.signature) for a in body.attestations)
+        sync = getattr(body, "sync_aggregate", None)
+        if sync is not None:
+            out.append(bytes(sync.sync_committee_signature))
+    return out
+
+
 def _publish(timings: dict) -> None:
-    from ..common.tracing import TRACER
     LAST_REPLAY_TIMINGS.clear()
     LAST_REPLAY_TIMINGS.update(timings)
     LAST_REPLAY_TIMINGS.update(_COUNTERS)
@@ -133,7 +160,8 @@ class EpochReplayer:
     :meth:`apply_window`.
 
     ``verify_signatures=True`` collects every block's sets and verifies
-    them as ONE batch whose verdict gates the whole window; off, the
+    them in chunks, sent while later blocks apply, whose verdicts
+    together gate the whole window; off, the
     window replays trusted blocks (store rebuild) with no signature
     work.  ``state_root_fn`` supplies store-known roots; the blocks' own
     claimed roots fill the gaps.  ``post_block_hook(state, signed)``
@@ -149,7 +177,8 @@ class EpochReplayer:
                  pubkey_cache=None,
                  sig_dispatcher=None,
                  boundary_root_check: bool = True,
-                 fallback: bool = True):
+                 fallback: bool = True,
+                 span_prefix: str = "replay"):
         self.state = state
         self.preset = preset
         self.spec = spec
@@ -160,7 +189,12 @@ class EpochReplayer:
         self.sig_dispatcher = sig_dispatcher
         self.boundary_root_check = boundary_root_check
         self.fallback = fallback
+        self.span_prefix = span_prefix
         self.post_block_hook: Optional[Callable] = None
+        # The last window's signature sets by padded signer count K, and
+        # its stage timings (the ``replay`` stage source's keys).
+        self.sets_by_k: Dict[int, int] = {}
+        self.timings: dict = {}
 
     # -- internals ----------------------------------------------------
 
@@ -173,10 +207,12 @@ class EpochReplayer:
 
     def _apply(self, state, blocks: Sequence, root_fn, strategy,
                sets: Optional[List[B.SignatureSet]],
-               slices: Optional[List[Tuple[int, int, int, int]]]):
+               slices: Optional[List[Tuple[int, int, int, int]]],
+               after_block: Optional[Callable[[], None]] = None):
         """The fused forward pass.  Mutates ``state`` through the window;
         harvests each block's signature sets into ``sets`` with per-block
-        ``(index, slot, start, end)`` slices for the bisect path."""
+        ``(index, slot, start, end)`` slices for the bisect path, and
+        calls ``after_block`` after each block."""
         for i, signed in enumerate(blocks):
             slot = int(signed.message.slot)
             if slot <= int(state.slot):
@@ -206,12 +242,25 @@ class EpochReplayer:
                 slices.append((i, slot, start, len(sets)))
             if self.post_block_hook is not None:
                 self.post_block_hook(state, signed)
+            if after_block is not None:
+                after_block()
         return state
 
     def _bisect_signatures(self, blocks, sets, slices) -> None:
-        """Batch verdict was False: re-verify per-block slices serially
-        to name the offender (the differential tests pin exactness)."""
-        for i, slot, start, end in slices:
+        """Batch verdict was False: name the FIRST block whose sets fail
+        (the differential tests pin exactness) by halving the per-block
+        slices, left half first — a half fails iff it holds a failing
+        block — so a 64-block window costs ~7 verifies, not 64."""
+        lo, hi = 0, len(slices)
+        while hi - lo > 1:
+            mid = (lo + hi) // 2
+            if B.verify_signature_sets(
+                    sets[slices[lo][2]:slices[mid - 1][3]]):
+                lo = mid
+            else:
+                hi = mid
+        if slices:
+            i, slot, start, end = slices[lo]
             if not B.verify_signature_sets(sets[start:end]):
                 raise WindowSignaturesInvalid(
                     f"window signature batch invalid: block at slot "
@@ -277,36 +326,60 @@ class EpochReplayer:
         slices: Optional[List[Tuple[int, int, int, int]]] = \
             [] if verify else None
 
-        t0 = time.perf_counter()
-        state = self._apply(self.state, blocks, root_fn, strategy,
-                            sets, slices)
-        t1 = time.perf_counter()
-
-        # ONE window-wide dispatch: the batch verifies on a worker
-        # thread (mesh-sharded on a TPU backend) while the boundary root
-        # hashes below.
-        batch = None
-        if verify and sets:
+        dispatcher, batches, sent = None, [], 0
+        chunk = _dispatch_chunk()
+        if verify:
             from .sig_dispatch import get_dispatcher
             dispatcher = self.sig_dispatcher or get_dispatcher()
-            batch = dispatcher.submit(sets, slot=int(blocks[-1].message.slot))
+            B.prefetch_signatures(_window_signatures(blocks))
+
+        def send(final: bool = False) -> None:
+            nonlocal sent
+            while len(sets) - sent >= chunk or (final and len(sets) > sent):
+                part = sets[sent:sent + chunk]
+                sent += len(part)
+                batches.append(dispatcher.submit(
+                    part, slot=int(blocks[-1].message.slot)))
+
+        span = self.span_prefix
+        t0 = time.perf_counter()
+        with TRACER.span(f"{span}.apply", cat="state_transition",
+                         blocks=len(blocks)):
+            state = self._apply(self.state, blocks, root_fn, strategy,
+                                sets, slices, send if verify else None)
+        t1 = time.perf_counter()
+        self.sets_by_k = {}
+        for s in sets or ():
+            k = _next_pow2(max(1, len(s.signing_keys)))
+            self.sets_by_k[k] = self.sets_by_k.get(k, 0) + 1
+
+        # The window's last chunk: it verifies on a worker thread while
+        # the boundary root hashes below.
+        if verify:
+            send(final=True)
         t2 = time.perf_counter()
 
         # ONE computed root at the boundary (vs one per block serially),
         # checked against the final block's claim.
         boundary_ok = True
         if self.boundary_root_check:
-            boundary_ok = (bytes(state.tree_hash_root())
-                           == bytes(blocks[-1].message.state_root))
+            with TRACER.span(f"{span}.boundary_root",
+                             cat="state_transition"):
+                boundary_ok = (bytes(state.tree_hash_root())
+                               == bytes(blocks[-1].message.state_root))
         t3 = time.perf_counter()
 
         verdict = True
-        if batch is not None:
-            try:
-                verdict = batch.join()
-            except Exception as e:
-                raise WindowSignaturesInvalid(
-                    f"window signature dispatch failed: {e}") from e
+        if batches:
+            with TRACER.span(f"{span}.sig_join", cat="state_transition"):
+                for batch in batches:
+                    try:
+                        verdict = batch.join()
+                    except Exception as e:
+                        raise WindowSignaturesInvalid(
+                            f"window signature dispatch failed: {e}") from e
+                    if not verdict:
+                        break
         t4 = time.perf_counter()
 
         timings = {
@@ -318,7 +391,8 @@ class EpochReplayer:
             "sets": len(sets) if sets else 0,
             "path": "batched",
         }
-        if batch is not None:
+        self.timings = timings
+        if batches:
             h2d = _set_bytes(sets)
             from ..common.device_ledger import (LEDGER,
                                                 REPLAY_WINDOW_BUDGET)

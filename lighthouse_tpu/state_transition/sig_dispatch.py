@@ -58,7 +58,16 @@ import threading
 import time
 from typing import Callable, List, Optional, Tuple
 
+from ..common.metrics import REGISTRY
 from ..common.tracing import TRACER
+
+# Envelope paths on which the device answered.
+DEVICE_PATHS = ("device", "device_retry", "probe")
+
+_BATCHES = REGISTRY.counter(
+    "block_sig_batches_total",
+    "block-signature batches verified, by whether the device or the "
+    "host served them", labelnames=("path",))
 
 # Stats of the most recent block-signature verdict (overlapped OR the
 # synchronous oracle path) — read via tracing.stage_split("block_sigs").
@@ -202,6 +211,8 @@ class BlockSigDispatcher:
         batch.stats["device_verify_ms"] = round(
             (time.perf_counter() - t0) * 1e3, 3)
         batch.stats["path"] = path
+        _BATCHES.labels(
+            path="device" if path in DEVICE_PATHS else "host").inc()
         batch._complete(ok)
 
     def _verify(self, sets) -> Tuple[bool, str]:

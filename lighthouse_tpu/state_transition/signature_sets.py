@@ -51,7 +51,16 @@ class PubkeyCache:
         per index — committee-sized per-index loops were measurable
         block time).  Returns the keys in ``indices`` order."""
         by_index = self._by_index
-        missing = {int(i) for i in indices if int(i) not in by_index}
+        idx = (indices.tolist() if isinstance(indices, np.ndarray)
+               else [int(i) for i in indices])
+        try:
+            # Every key cached (the steady state): one C-level conversion
+            # and one dict sweep — a chain segment at 2^20 validators
+            # looks up ~4M keys a batch.
+            return [by_index[i] for i in idx]
+        except KeyError:
+            pass
+        missing = {i for i in idx if i not in by_index}
         if missing:
             col = registry.col("pubkey")
             for i in missing:
@@ -59,7 +68,7 @@ class PubkeyCache:
                 pk = PublicKey.deserialize(raw)
                 by_index[i] = pk
                 self._index_by_pubkey[raw] = i
-        return [by_index[int(i)] for i in indices]
+        return [by_index[i] for i in idx]
 
     def get_many_bytes(self, registry, raws) -> list[PublicKey]:
         """Batched lookup by compressed ENCODING (the sync-committee

@@ -19,6 +19,7 @@ chain immediately.
 from __future__ import annotations
 
 import enum
+import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -34,6 +35,8 @@ from ..beacon_chain.errors import (
     ProposalSignatureInvalid,
     StateRootMismatch,
 )
+from ..common.metrics import REGISTRY
+from ..common.tracing import TRACER
 from ..state_transition.batch_replay import (
     EpochReplayer,
     WindowBlockInvalid,
@@ -50,6 +53,16 @@ __all__ = ["Outcome", "SegmentResult", "process_chain_segment"]
 # peer burns attempts without changing the verdict.
 _DETERMINISTIC = (InvalidBlock, InvalidSignatures, StateRootMismatch,
                   IncorrectProposer, ProposalSignatureInvalid)
+
+
+_SETS = REGISTRY.counter(
+    "chain_segment_sets_total",
+    "signature sets of committed batched segments, by padded signer "
+    "count K", labelnames=("k",))
+_STAGE_S = REGISTRY.counter(
+    "chain_segment_stage_seconds_total",
+    "wall seconds of batched segment import, by stage",
+    labelnames=("stage",))
 
 
 class Outcome(enum.Enum):
@@ -105,12 +118,25 @@ def process_chain_segment(chain, blocks) -> SegmentResult:
 
     Batched path (knob auto/on, window long enough, parent-linked):
     apply the whole window through :class:`EpochReplayer` on a copy of
-    the parent state — ONE sharded signature batch, known state roots,
-    ONE boundary root — and only on a passing verdict commit every
+    the parent state — its signature sets verified in chunks under the
+    transition, known state roots, ONE boundary root — and only on a
+    passing verdict commit every
     block through the chain's atomic import (fork choice, store batch,
     attester caches, head recompute).  A failed window commits NOTHING.
-    Serial path otherwise (the differential oracle)."""
+    Serial path otherwise (the differential oracle).
+
+    The batched path runs under the span ``chain_segment`` (children
+    ``chain_segment.{apply,boundary_root,sig_join,commit}``); a
+    committed segment adds its stage seconds to
+    ``chain_segment_stage_seconds_total{stage}`` and its signature sets
+    to ``chain_segment_sets_total{k}``."""
     blocks = list(blocks)
+    with TRACER.span("chain_segment", cat="sync",
+                     blocks=len(blocks)) as sp:
+        return _process_segment(chain, blocks, sp)
+
+
+def _process_segment(chain, blocks, sp) -> SegmentResult:
     if not blocks:
         return SegmentResult(Outcome.OK, 0)
 
@@ -152,7 +178,8 @@ def process_chain_segment(chain, blocks) -> SegmentResult:
     snapshots: list = []
     rep = EpochReplayer(pre_state, chain.preset, chain.spec, chain.T,
                         verify_signatures=True,
-                        pubkey_cache=chain.pubkey_cache)
+                        pubkey_cache=chain.pubkey_cache,
+                        span_prefix="chain_segment")
     rep.post_block_hook = lambda state, signed: snapshots.append(
         state.copy())
     try:
@@ -173,28 +200,57 @@ def process_chain_segment(chain, blocks) -> SegmentResult:
     except Exception as e:
         return SegmentResult(Outcome.RETRY, 0, error=e, batched=True)
 
+    sp.set(sets=sum(rep.sets_by_k.values()))
+    timings = rep.timings
     # Window verdict passed — commit every block through the atomic
     # import path (store batch + fork choice + caches + head).
+    t0 = time.perf_counter()
+    with TRACER.span("chain_segment.commit", cat="sync"):
+        res = _commit(chain, fresh, snapshots)
+    if res.outcome is Outcome.OK:
+        for stage, key in (("apply", "apply_ms"), ("collect", "collect_ms"),
+                           ("boundary_root", "root_ms"),
+                           ("sig_join", "verify_ms")):
+            _STAGE_S.labels(stage=stage).inc(timings.get(key, 0.0) / 1e3)
+        _STAGE_S.labels(stage="commit").inc(time.perf_counter() - t0)
+        for k, n in rep.sets_by_k.items():
+            _SETS.labels(k=k).inc(n)
+    return res
+
+
+def _commit(chain, fresh, snapshots) -> SegmentResult:
+    """Import the window's blocks in order.  The head is recomputed once,
+    for the last block (and after a failure, for the blocks already in)."""
     imported = 0
-    for (root, b), state in zip(fresh, snapshots):
-        slot = int(b.message.slot)
-        chain.per_slot_task(slot)
-        try:
-            chain.observed_block_producers.observe(
-                slot, int(b.message.proposer_index), root)
-        except Exception:
-            pass  # dedup bookkeeping must not fail a verified window
-        ex = ExecutedBlock(signed_block=b, block_root=root,
-                           post_state=state)
-        try:
-            chain._import_block(ex, is_timely=False)
-        except BlockIsAlreadyKnown:
-            continue
-        except _DETERMINISTIC as e:
-            return SegmentResult(Outcome.FATAL, imported, error=e,
-                                 batched=True)
-        except Exception as e:
-            return SegmentResult(Outcome.RETRY, imported, error=e,
-                                 batched=True)
-        imported += 1
+    last = len(fresh) - 1
+    try:
+        for i, ((root, b), state) in enumerate(zip(fresh, snapshots)):
+            slot = int(b.message.slot)
+            chain.per_slot_task(slot, advance_head=False)
+            try:
+                chain.observed_block_producers.observe(
+                    slot, int(b.message.proposer_index), root)
+            except Exception:
+                pass  # dedup bookkeeping must not fail a verified window
+            ex = ExecutedBlock(signed_block=b, block_root=root,
+                               post_state=state)
+            try:
+                if i == last:
+                    # The head at the last block's parent, so its
+                    # light-client updates see the head they extend.
+                    chain.recompute_head()
+                chain._import_block(ex, is_timely=False,
+                                    light_client=i == last,
+                                    recompute_head=False)
+            except BlockIsAlreadyKnown:
+                continue
+            except _DETERMINISTIC as e:
+                return SegmentResult(Outcome.FATAL, imported, error=e,
+                                     batched=True)
+            except Exception as e:
+                return SegmentResult(Outcome.RETRY, imported, error=e,
+                                     batched=True)
+            imported += 1
+    finally:
+        chain.recompute_head()
     return SegmentResult(Outcome.OK, imported, batched=True)

@@ -423,6 +423,15 @@ class SpecTypes:
                     out.__dict__["_thc"] = thc.copy()
                 if self.__dict__.get("_device_resident"):
                     out.__dict__["_device_resident"] = True
+                # An epoch's committees are fixed by the chain before the
+                # epoch starts (seed and active set), so the copy keeps
+                # the shufflings (the reference's committee caches ride
+                # state clones the same way): a chain segment's
+                # per-block snapshots would otherwise each re-shuffle
+                # 2^20 validators for their attestations.
+                caches = self.__dict__.get("_committee_caches")
+                if caches:
+                    out.__dict__["_committee_caches"] = dict(caches)
                 return out
 
             genesis_time: uint64

@@ -61,6 +61,8 @@ class RegistryCache:
         self.count = 0                                    # records at last root
         self.tree: IncrementalMerkleCache | None = None
         self._pending = None                              # (thread, [levels])
+        # ``stored`` arrays shared with a copy(): copied before a write.
+        self._stored_shared = False
 
     # -- cold builds ---------------------------------------------------------
 
@@ -85,6 +87,7 @@ class RegistryCache:
     def _snapshot(self, reg, n: int) -> None:
         self.stored = {c: np.array(getattr(reg, c)[:n])
                        for c in reg._COLUMNS}
+        self._stored_shared = False
         self.count = n
         reg._dirty_cols.clear()
         reg._dirty_rows.clear()
@@ -124,6 +127,9 @@ class RegistryCache:
         return np.nonzero(dirty)[0]
 
     def _update_stored(self, reg, idx: np.ndarray, n: int) -> None:
+        if self._stored_shared and idx.size:
+            self.stored = {k: v.copy() for k, v in self.stored.items()}
+            self._stored_shared = False
         for cname in reg._COLUMNS:
             col = getattr(reg, cname)
             st = self.stored[cname]
@@ -210,8 +216,10 @@ class RegistryCache:
         if self._pending is not None:
             self._finish_pending()
         out = RegistryCache.__new__(RegistryCache)
-        out.stored = (None if self.stored is None
-                      else {k: v.copy() for k, v in self.stored.items()})
+        # The column copies are shared copy-on-write, as the registry's
+        # own columns are (``ValidatorRegistry.copy``).
+        out.stored = None if self.stored is None else dict(self.stored)
+        self._stored_shared = out._stored_shared = self.stored is not None
         out.count = self.count
         out.tree = None if self.tree is None else self.tree.copy()
         out._pending = None

@@ -1188,6 +1188,51 @@ int bls381_g2_mul(const uint64_t *p, const uint64_t *scalar,
     return 1;
 }
 
+// The curve half of a G2 signature's decompression, for its x (standard
+// form, 12 u64): a y with y² = x³ + 4(1 + u), then the endomorphism
+// subgroup check ψ(P) == [x]P (x the BLS parameter; host oracle
+// `hash_to_curve.g2_subgroup_check_fast`) — membership is the same for
+// (x, ±y), so the caller picks the sign its flag bit names.  Writes y
+// (standard form, 12 u64).  Returns 0 if x is not on the curve, 2 if the
+// point is on it but outside G2, 1 otherwise.
+int bls381_g2_y_checked(const uint64_t *x_std, uint64_t *y_out) {
+    Fp2 x, rhs, b, y;
+    fp_from_limbs(x.c0, x_std);
+    fp_from_limbs(x.c1, x_std + 6);
+    fp2_sqr(rhs, x);
+    fp2_mul(rhs, rhs, x);
+    fp_add(b.c0, *fp_one(), *fp_one());
+    fp_add(b.c0, b.c0, b.c0);           // 4
+    b.c1 = b.c0;                        // 4(1 + u)
+    fp2_add(rhs, rhs, b);
+    if (fp2_is_zero(rhs)) return 2;     // y = 0: a point of order 2
+    if (!fp2_sqrt_or_z(y, rhs)) return 0;
+    G2Jac p, xp;
+    g2j_from_affine(p, x, y);
+    g2j_mul_xabs(xp, p);
+    g2j_neg(xp, xp);                    // [x]P, x < 0
+    // ψ(P) from the affine P, compared with the jacobian [x]P without an
+    // inversion: X == ψx·Z², Y == ψy·Z³.
+    Fp2 px, py, z2, z3, t2;
+    fp2_conj(px, x);
+    fp2_mul(px, *c2(H2C_PSI_CX), px);
+    fp2_conj(py, y);
+    fp2_mul(py, *c2(H2C_PSI_CY), py);
+    if (fp2j_is_inf(xp)) return 2;
+    fp2_sqr(z2, xp.Z);
+    fp2_mul(z3, z2, xp.Z);
+    fp2_mul(t2, px, z2);
+    if (!fp2_eq(t2, xp.X)) return 2;
+    fp2_mul(t2, py, z3);
+    if (!fp2_eq(t2, xp.Y)) return 2;
+    Fp one_std, t;
+    std::memset(&one_std, 0, sizeof one_std);
+    one_std.l[0] = 1;
+    fp_mul(t, y.c0, one_std); std::memcpy(y_out, t.l, 48);
+    fp_mul(t, y.c1, one_std); std::memcpy(y_out + 6, t.l, 48);
+    return 1;
+}
+
 // Sum n affine G1 points (12 u64 each, standard form, non-infinity —
 // callers filter identities).  Writes the affine sum to out[12]; returns
 // 1 on a finite sum, 0 if the sum is the identity (out untouched).

@@ -266,6 +266,52 @@ def test_pubkey_table_lru_eviction():
             np.frombuffer(TB._g1_aff_col(junk[0]), np.uint32)).all()
 
 
+def test_pubkey_table_indices_of_matches_index_of():
+    """The batched lookup gives what per-key lookups give — repeats,
+    keys new to the table in first-seen order, a capacity doubling in
+    between — and marks every key used in the current generation."""
+    import numpy as np
+    from lighthouse_tpu.crypto import tpu_backend as TB
+
+    pts = [bls.SecretKey(300 + i).public_key().point for i in range(12)]
+    keys = pts[:5] + pts[2:4] + pts[5:] + [pts[0]]
+    one = TB._DevicePubkeyTable(initial=8)
+    for p in pts[:3]:
+        one.index_of(p)
+    many = TB._DevicePubkeyTable(initial=8)
+    for p in pts[:3]:
+        many.index_of(p)
+    many.maybe_reset()
+    one.maybe_reset()
+    want = [one.index_of(p) for p in keys]
+    got = many.indices_of(keys)
+    assert got.dtype == np.int32 and got.tolist() == want
+    assert (many._host[:, :many._n] == one._host[:, :one._n]).all()
+    assert all(many._last_used[p] == many._gen for p in keys)
+
+
+def test_pubkey_table_add_all_matches_index_of():
+    """The bulk fill gives the columns, indices and capacity growth that
+    one ``index_of`` per key gives, skips keys already resident, and
+    re-uploads the device copy."""
+    import numpy as np
+    from lighthouse_tpu.crypto import tpu_backend as TB
+
+    pts = [bls.SecretKey(700 + i).public_key().point for i in range(20)]
+    one = TB._DevicePubkeyTable(initial=8)
+    for p in pts[:3] + pts:
+        one.index_of(p)
+    bulk = TB._DevicePubkeyTable(initial=8)
+    bulk.index_of(pts[0])
+    bulk.device()
+    bulk.add_all(pts[:3] + pts + pts[5:7])
+    assert bulk._n == one._n == 21 and bulk._host.shape == one._host.shape
+    assert (bulk._host == one._host).all()
+    assert all(bulk._index[p] == one._index[p] for p in pts)
+    assert bulk._device is None
+    np.testing.assert_array_equal(np.asarray(bulk.device()), one._host)
+
+
 @pytest.mark.slow
 def test_aggregate_verify_many_distinct_messages():
     """VERDICT r4 weak #9 shape: a deposit-block-style aggregate_verify

@@ -226,6 +226,40 @@ def test_bls_pipeline_verdicts_bit_identical(monkeypatch):
         assert mono == piped == want
 
 
+@pytest.mark.parametrize("flags,want", [
+    ((0, 0, 0), True), ((0, 1, 0), False), ((1, 0, 0), False),
+    ((0, 0, 1), False)])
+def test_identity_flag_carried_through_sub_batches(monkeypatch, flags,
+                                                   want):
+    """The device route carries ONE fixed-shape identity-key flag through
+    its sub-batches in dispatch order (``_cell_bad`` ORs each chunk in),
+    and the verdict is the product check AND NOT the flag: a flag raised
+    by any sub-batch refuses the call, whatever the call's chunk count.
+    The per-chunk kernels are stood in for; the flag programs are real."""
+    from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.crypto import tpu_backend as TB
+
+    monkeypatch.setenv("LIGHTHOUSE_TPU_PIPELINE_SETS", "2")
+    carried = []
+
+    def cells(table, staged, bad):
+        carried.append(bool(bad))
+        live = np.ones((1, 128), np.int32)
+        flag = np.full((1, 128), flags[len(carried) - 1], np.int32)
+        return np.zeros((384, 128), np.uint32), TB._cell_bad(flag, live,
+                                                             bad)
+
+    monkeypatch.setattr(TB, "_pipeline_cells", cells)
+    monkeypatch.setattr(TB, "_fold_blocks", lambda blocks: blocks[0])
+    monkeypatch.setattr(TB, "_finalize",
+                        lambda prod: np.ones((1, 1), np.int32))
+    sk = bls.SecretKey(4242)
+    entries = [(sk.sign(m).point, [sk.public_key().point], m)
+               for m in (b"flag-%d" % i for i in range(6))]
+    assert TB._dispatch_pallas(entries, lambda: 1) is want
+    assert carried == [any(flags[:i]) for i in range(3)]
+
+
 def test_donated_entry_points_exist(monkeypatch):
     """The hot path's finalize carries buffer donation on TPU (the
     folded product is batch-local), while the reusable-input entry stays

@@ -108,10 +108,59 @@ def test_prepare_cell(chip_compile, K):
     assert text.count("tpu_custom_call") >= 1
 
 
+def test_prepare_cell_committee_width(one_chip):
+    """The prepare program at K = 512 (a mainnet committee's aggregate,
+    the range-sync cell's bucket) over a 2^20-key table: the 16 MiB
+    gathered key block fits the kernel's VMEM and the program the chip."""
+    from jax._src import compilation_cache as cc
+
+    from lighthouse_tpu.crypto import limb_field as LF
+    from lighthouse_tpu.crypto import tpu_backend as TB
+
+    K = 512
+    old_cache, old_mxu = jax.config.jax_enable_compilation_cache, \
+        LF._MXU_FLAG
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    LF._MXU_FLAG = True
+    try:
+        args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+                for shape, dt in (((64, 1 << 21), U32), ((K * S,), I32),
+                                  ((1, K * S), I32), ((1, S), U32),
+                                  ((1, S), U32))]
+        compiled = TB._prepare_cell.lower(*args, K=K).compile()
+    finally:
+        LF._MXU_FLAG = old_mxu
+        jax.config.update("jax_enable_compilation_cache", old_cache)
+        cc.reset_cache()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 4 << 30
+
+
 def test_product_fold_pair(chip_compile):
     from lighthouse_tpu.crypto import tpu_backend as TB
 
     text = chip_compile(TB._fold_pair, [((384, S), U32)] * 2)
+    assert "tpu_custom_call" in text
+
+
+def test_exported_fold_pair_for_the_chip(chip_compile, one_chip):
+    """The exported-program cache's chip path: the fold program exported
+    for the TPU here, read back, and its same-named caller compiled for
+    the described v5e — the module keeps the jitted name the device
+    trace reads, and the Mosaic kernel survives the round trip."""
+    from lighthouse_tpu.common import program_cache as PC
+    from lighthouse_tpu.crypto import tpu_backend as TB
+
+    avals = [jax.ShapeDtypeStruct((384, S), U32)] * 2
+    exp = jax.export.export(TB._fold_pair.jitted,
+                            platforms=("tpu",))(*avals)
+    back = jax.export.deserialize(exp.serialize())
+    caller = jax.jit(PC._named(back.call, TB._fold_pair.name))
+    args = [jax.ShapeDtypeStruct((384, S), U32, sharding=one_chip)] * 2
+    text = caller.lower(*args).compile().as_text()
+    assert "HloModule jit__fold_pair" in text
     assert "tpu_custom_call" in text
 
 
